@@ -465,6 +465,10 @@ def main() -> None:
                     help="where BENCH_*.json reports are written")
     args = ap.parse_args()
 
+    from repro.jaxcache import enable_compile_cache
+
+    enable_compile_cache()
+
     print("name,us_per_call,derived")
     sections = {
         "fig56": lambda: bench_fig56(args.full),

@@ -38,13 +38,13 @@ tb.write(sys.argv[1])
 serializer.write_turtle(tb.doc, sys.argv[1] + "/mapping.ttl")
 EOF
 
-# the same sources, unsharded and sharded (2 shards, 2 build workers)
+# the same sources, unsharded and sharded (2 shards)
 python -m repro.launch.rdfize \
     --mapping "$WORK/mapping.ttl" --data-root "$WORK" \
     --out "$WORK/kg.kgz" --emit kgz
 python -m repro.launch.rdfize \
     --mapping "$WORK/mapping.ttl" --data-root "$WORK" \
-    --out "$WORK/kg.shards.json" --emit kgz --shards 2 --shard-workers 2
+    --out "$WORK/kg.shards.json" --emit kgz --shards 2
 
 # 1) in-process shard session: byte-identical to the single store,
 #    routed insert touches exactly one shard

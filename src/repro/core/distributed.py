@@ -24,11 +24,11 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import hashing, hashset, pjtt
 from repro.core.hashing import EMPTY
-from repro.compat import shard_map
 
 # Default slack factor for the fixed-capacity all_to_all bins.  With random
 # hash owners the per-bucket load is Binomial(n_local, 1/S); 4x the mean keeps
@@ -134,7 +134,7 @@ def distributed_insert(mesh, table: ShardedPTT, key_hi, key_lo, valid):
     spec_t = P(axes)
     spec_b = P(axes)
     out = jax.jit(
-        shard_map(
+        jax.shard_map(
             fn,
             mesh=mesh,
             check_vma=False,
@@ -153,7 +153,7 @@ class ShardedPJTT(NamedTuple):
     ssubj: jnp.ndarray  # int32[n_shards, cap]
 
 
-_PAD_KEY = jnp.int32(2147483647)  # sorts to the end; never a dictionary id
+_PAD_KEY = np.int32(2147483647)  # sorts to the end; never a dictionary id
 
 
 def build_distributed_pjtt(mesh, parent_keys, parent_subjects):
@@ -180,7 +180,7 @@ def build_distributed_pjtt(mesh, parent_keys, parent_subjects):
 
     spec_b = P(axes)
     skeys, ssubj, ovf = jax.jit(
-        shard_map(
+        jax.shard_map(
             fn,
             mesh=mesh,
             check_vma=False,
@@ -233,7 +233,7 @@ def distributed_ojm_probe(mesh, index: ShardedPJTT, child_keys, max_matches: int
 
     spec_b = P(axes)
     subs, vals, ovf = jax.jit(
-        shard_map(
+        jax.shard_map(
             fn,
             mesh=mesh,
             check_vma=False,

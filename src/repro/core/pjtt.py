@@ -26,13 +26,14 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import hashing
 from repro.core.hashset import next_pow2
 
 MAX_PROBE_ROUNDS = 64
-_KEY_EMPTY = jnp.int32(-1)  # join keys are dictionary ids >= 0
-_SUBJ_MASKED = jnp.int32(-1)
+_KEY_EMPTY = np.int32(-1)  # join keys are dictionary ids >= 0
+_SUBJ_MASKED = np.int32(-1)
 _I32_MAX = jnp.iinfo(jnp.int32).max
 
 

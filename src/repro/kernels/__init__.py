@@ -13,12 +13,9 @@ in ``ops.py``):
 * ``nested_join``  — the paper's *baseline* nested-loop join as a blocked
   all-pairs kernel (child block resident in VMEM, parent tiles streamed).
 
-``scan_join.py`` serves the query side: the fused scan/bind-join chain
-behind the small-batch dispatch fast path (``repro.serve.fastpath``) —
-one ``grid=(batch,)`` launch covering binary-search range scans and
-bind-join expansion for 1–3 pattern plans, with a vmapped pure-jnp
-reference formulation of the same chain math for CPU hosts.
-
 Kernels target TPU (BlockSpec VMEM tiling) and are validated on CPU with
-``interpret=True`` against the oracles across shape/dtype sweeps.
+``interpret=True`` against the oracles across shape/dtype sweeps.  None
+of them is on the engine's path, and only ``hash_mix`` compiles for a TPU
+v5e today: Mosaic refuses ``bucket_dedup``'s block shape and
+``nested_join``'s ``cumsum``.
 """

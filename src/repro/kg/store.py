@@ -104,6 +104,8 @@ class TripleStore:
     p: np.ndarray
     o: np.ndarray
     indexes: dict[str, Index]
+    # where the device copies live (None: JAX's default device); see place()
+    device: "jax.Device | None" = dataclasses.field(default=None, repr=False)
 
     # lazy caches (device copies of index columns; rendered-term lookup)
     _dev: dict = dataclasses.field(default_factory=dict, repr=False)
@@ -249,6 +251,20 @@ class TripleStore:
     def n_triples(self) -> int:
         return len(self.s)
 
+    def place(self, device) -> "TripleStore":
+        """Pin the store's device arrays — index columns, packed keys,
+        primary-term row starts, value tables, and the executor's
+        per-dispatch inputs — to ``device`` (``None``: JAX's default).
+        A store serves from one device, so this precedes its first
+        query."""
+        if self._dev and device != self.device:
+            raise ValueError(
+                f"store already holds device arrays on {self.device}; "
+                f"place it before its first query"
+            )
+        self.device = device
+        return self
+
     @property
     def n_terms(self) -> int:
         return len(self.term_pat)
@@ -257,7 +273,8 @@ class TripleStore:
         """Index columns as device arrays (cached) for the jitted scans."""
         if order not in self._dev:
             self._dev[order] = tuple(
-                jnp.asarray(c) for c in self.indexes[order].cols
+                jax.device_put(c, self.device)
+                for c in self.indexes[order].cols
             )
         return self._dev[order]
 
@@ -291,7 +308,10 @@ class TripleStore:
                 (packed & 0xFFFFFFFF).astype(np.uint32)
                 ^ np.uint32(0x80000000)
             ).view(np.int32)
-            self._dev[cache_key] = (jnp.asarray(khi), jnp.asarray(klo))
+            self._dev[cache_key] = (
+                jax.device_put(khi, self.device),
+                jax.device_put(klo, self.device),
+            )
         return self._dev[cache_key]
 
     def device_primary_starts(self, order: str):
@@ -305,7 +325,7 @@ class TripleStore:
             starts = np.searchsorted(
                 c0, np.arange(self.n_terms + 1)
             ).astype(np.int32)
-            self._dev[cache_key] = jnp.asarray(starts)
+            self._dev[cache_key] = jax.device_put(starts, self.device)
         return self._dev[cache_key]
 
     def primary_rounds(self, order: str) -> int:

@@ -33,7 +33,6 @@ import traceback
 import jax
 
 from repro.launch.mesh import make_production_mesh
-from repro.compat import set_mesh
 
 RESULTS = os.path.abspath(os.path.join(os.path.dirname(__file__), "../../..", "results"))
 
@@ -103,7 +102,7 @@ def collective_bytes(hlo_text: str) -> dict:
 
 def _compile_costs(spec, mesh) -> dict:
     t0 = time.time()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(spec.fn, donate_argnums=spec.donate)
         lowered = jitted.lower(*spec.args)
         t_lower = time.time() - t0
@@ -282,7 +281,7 @@ def main() -> None:
                     continue
                 print(f"[dryrun] {key} ...", flush=True)
                 try:
-                    with set_mesh(mesh):
+                    with jax.set_mesh(mesh):
                         jitted = jax.jit(spec.fn, donate_argnums=spec.donate)
                         lowered = jitted.lower(*spec.args)
                         compiled = lowered.compile()

@@ -43,6 +43,10 @@ def main() -> None:
                          "(dispatch / redispatch spans; open in Perfetto)")
     args = ap.parse_args()
 
+    from repro.jaxcache import enable_compile_cache
+
+    enable_compile_cache()
+
     from repro import api, obs
     from repro.kg import persist
 

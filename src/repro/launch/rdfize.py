@@ -21,8 +21,8 @@ reads, shared subject/join templates are evaluated once, and rules execute
 group-by-group along the plan's DAG.  ``--explain-mapping`` prints the
 planner's decisions as a tree — kept/pruned columns per source, factored
 terms, rule groups — and exits without building anything.  With
-``--shards N --shard-workers M`` and a multi-group plan, whole rule
-groups build in parallel worker processes before the shard stores do.
+``--shards N`` the KG is hash-partitioned into N ``.kgz`` shard stores
+plus a manifest at ``--out``.
 
 Mirrors the paper's tool: parse the RML document, plan, execute with the
 PTT/PJTT operators, emit N-Triples, print the per-predicate φ statistics.
@@ -43,7 +43,7 @@ def _print_stats(stats) -> None:
         )
 
 
-def main() -> None:
+def main(argv: list[str] | None = None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--mapping", required=True)
     ap.add_argument("--data-root", default=".")
@@ -71,15 +71,15 @@ def main() -> None:
                          "hash into N shard stores plus a manifest at "
                          "--out (serve it with launch.serve, query it "
                          "with repro.api.connect)")
-    ap.add_argument("--shard-workers", type=int, default=0, metavar="M",
-                    help="build rule groups, then shard stores, across M "
-                         "spawned worker processes (default: serial "
-                         "in-process)")
     ap.add_argument("--trace", default=None, metavar="OUT.json",
                     help="record a Chrome trace-event JSON of the run "
                          "(per-block read/project/encode spans with "
                          "--stream; open in Perfetto / chrome://tracing)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+
+    from repro.jaxcache import enable_compile_cache
+
+    enable_compile_cache()
 
     from repro import obs
     from repro.core.executor import create_kg
@@ -96,7 +96,6 @@ def main() -> None:
         doc = parser.parse_file(args.mapping)
     print(f"[rdfize] {len(doc.triples_maps)} triples maps from {args.mapping}")
     mapping_plan = not args.no_mapping_plan
-    mplan = None
     if mapping_plan:
         from repro.rml.plan import build_plan
 
@@ -106,45 +105,6 @@ def main() -> None:
               f"groups ({len(mplan.shared)} shared terms factored)")
     if args.shards and args.emit != "kgz":
         ap.error("--shards needs --emit kgz (shard stores are .kgz snapshots)")
-
-    group_parallel = (
-        args.out is not None
-        and args.emit == "kgz"
-        and args.shards
-        and args.shard_workers > 1
-        and mplan is not None
-        and len(mplan.groups) > 1
-    )
-    if group_parallel:
-        # whole rule groups are the unit of multiprocess work: each
-        # worker builds its group's sub-KG, the parent unions the
-        # rendered triples and hash-partitions them into shard stores
-        from repro.shard.ingest import ingest_mapping_sharded
-
-        with open(args.mapping, encoding="utf-8") as f:
-            mapping_text = f.read()
-        with obs.span("create_kg_grouped", cat="rdfize",
-                      groups=len(mplan.groups), workers=args.shard_workers):
-            manifest, stats, n_triples = ingest_mapping_sharded(
-                mapping_text, args.data_root, args.out, args.shards,
-                workers=args.shard_workers,
-                engine_opts=dict(
-                    engine=args.engine, join_strategy=args.join,
-                    batch_size=args.batch_size, stream=args.stream,
-                    block_rows=args.block_rows,
-                ),
-            )
-        print(f"[rdfize] {n_triples} unique triples "
-              f"({len(mplan.groups)} rule groups in parallel)")
-        _print_stats(stats)
-        sizes = ", ".join(str(s["n_triples"]) for s in manifest["shards"])
-        print(f"[rdfize] wrote {n_triples}-triple sharded KG "
-              f"({args.shards} shards: {sizes} triples) — manifest "
-              f"at {args.out}")
-        if args.trace:
-            n_ev = obs.save_trace(args.trace)
-            print(f"[rdfize] wrote {n_ev}-event trace to {args.trace}")
-        return
 
     with obs.span("create_kg", cat="rdfize", engine=args.engine,
                   stream=args.stream):
@@ -168,10 +128,7 @@ def main() -> None:
             with obs.span("emit_sharded", cat="rdfize", out=args.out,
                           shards=args.shards):
                 store = result.to_store()
-                manifest = shard_store(
-                    store, args.out, args.shards,
-                    workers=args.shard_workers,
-                )
+                manifest = shard_store(store, args.out, args.shards)
             sizes = ", ".join(
                 str(s["n_triples"]) for s in manifest["shards"]
             )

@@ -65,6 +65,8 @@ def main() -> None:
     args = ap.parse_args()
 
     if args.connect:
+        # client mode never touches JAX: the chip stays free for the
+        # server process
         if not args.query and not args.metrics:
             ap.error("--connect needs --query (or --metrics)")
         from repro import api
@@ -81,6 +83,10 @@ def main() -> None:
 
     if not args.kg:
         ap.error("provide --kg to serve, or --connect/--query for client mode")
+    from repro.jaxcache import enable_compile_cache
+
+    enable_compile_cache()
+
     from repro import obs
     from repro.kg.persist import is_manifest, open_store
     from repro.serve.server import KGServer

@@ -38,9 +38,9 @@ the executor never imports it (the view is duck-typed); ``live`` imports
 
 from __future__ import annotations
 
-import numpy as np
-
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.hashset import next_pow2
 from repro.data.terms import canonical_term
@@ -67,6 +67,7 @@ class OverlayView:
     ):
         self.base = base
         self.dictionary = base.dictionary
+        self.device = base.device
         self._new_terms = tuple(new_terms)
         self._new_ids = dict(new_ids)
         t0 = base.n_terms
@@ -109,7 +110,7 @@ class OverlayView:
         self.delta = TripleStore.build(
             base.dictionary, self.term_pat, self.term_val,
             cols[:, 0].copy(), cols[:, 1].copy(), cols[:, 2].copy(),
-        )
+        ).place(base.device)
         self._alive: dict[str, jnp.ndarray] = {}
 
     # -- store-like surface ---------------------------------------------------
@@ -145,10 +146,11 @@ class OverlayView:
         if a is None:
             perm = self.base.indexes[order].perm
             live = (~self.dead[perm]).astype(np.int64)
-            a = jnp.asarray(
+            a = jax.device_put(
                 np.concatenate(
                     [np.zeros(1, np.int64), np.cumsum(live)]
-                ).astype(np.int32)
+                ).astype(np.int32),
+                self.device,
             )
             self._alive[order] = a
         return a
@@ -340,6 +342,7 @@ class LiveStore:
         set (term ids = ranks of rendered terms, deterministic snapshot
         writer), regardless of how the previous base was built."""
         new = TripleStore.from_ntriples(self.rendered_triples())
+        new.place(self.base.device)
         self.base = new
         self._new_terms = []
         self._new_ids = {}

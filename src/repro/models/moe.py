@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.models import layers
-from repro.models.sharding import active_axes, current_mesh, shard_map
+from repro.models.sharding import active_axes
 
 
 class MoEConfig(NamedTuple):
@@ -113,7 +113,7 @@ def _forward_local(p, cfg: MoEConfig, x):
 
 
 def _forward_sharded(p, cfg: MoEConfig, x):
-    mesh = current_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     dp = tuple(a for a in mesh.axis_names if a in ("pod", "data"))
     b, s, d = x.shape
 
@@ -151,7 +151,7 @@ def _forward_sharded(p, cfg: MoEConfig, x):
         x_spec, y_spec = P(dp, None), P(dp, None)
     else:  # tiny decode batches: replicate the token stream
         x_spec, y_spec = P(None, None), P(None, None)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

@@ -22,7 +22,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.dedup_gather import gather_maybe_dedup
 from repro.models import layers
-from repro.models.sharding import active_axes, current_mesh, shard_map
+from repro.models.sharding import active_axes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -115,7 +115,7 @@ def _vocab_parallel_rows(table3, flat_ids, cfg: WideDeepConfig, mesh, dp):
         ids_spec, out_spec = P(dp), P(dp, None)
     else:  # tiny batches (retrieval_cand B=1): replicate the id stream
         ids_spec, out_spec = P(None), P(None, None)
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(None, "model", None), ids_spec),
@@ -137,7 +137,7 @@ def _fetch_rows(params_key, params, cfg: WideDeepConfig, sparse_ids):
     ).reshape(-1)
     axes = active_axes()
     if "model" in axes and "data" in axes:
-        mesh = current_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         dp = tuple(a for a in axes if a in ("pod", "data"))
         return _vocab_parallel_rows(table3, global_ids, cfg, mesh, dp)
     flat_table = table3.reshape(F * V, -1)

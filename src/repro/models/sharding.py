@@ -22,13 +22,8 @@ def _flatten_axes(spec: P):
             yield part
 
 
-# the version shims live in repro.compat (dependency-neutral); re-exported
-# here because model code reaches for them alongside constrain/active_axes
-from repro.compat import current_mesh, set_mesh, shard_map  # noqa: F401
-
-
 def active_axes() -> tuple:
-    mesh = current_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     return tuple(mesh.axis_names) if not mesh.empty else ()
 
 

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.models.sharding import active_axes, current_mesh, shard_map
+from repro.models.sharding import active_axes
 
 
 def _mesh_ready() -> bool:
@@ -46,7 +46,7 @@ def embed(table: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
     """(V, d) table, (B, S) int ids -> (B, S, d)."""
     if not _mesh_ready():
         return jnp.take(table, ids, axis=0)
-    mesh = current_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     n_model = mesh.shape["model"]
     dp = _dp_axes()
     V = table.shape[0]
@@ -69,7 +69,7 @@ def embed(table: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
         rows = jnp.where(ok[..., None], rows, 0)
         return jax.lax.psum(rows, "model")
 
-    out = shard_map(
+    out = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(P("model", "data"), ids_spec),
@@ -84,7 +84,7 @@ def tied_logits(table: jnp.ndarray, h: jnp.ndarray) -> jnp.ndarray:
     'model' (ready for the sharded-softmax loss)."""
     if not _mesh_ready():
         return h @ table.T
-    mesh = current_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     dp = _dp_axes()
     b, s, d = h.shape
     flat = h.reshape(-1, d)
@@ -97,7 +97,7 @@ def tied_logits(table: jnp.ndarray, h: jnp.ndarray) -> jnp.ndarray:
         full = jax.lax.all_gather(tbl, "data", axis=1, tiled=True)  # (V/m, d)
         return h_l @ full.T  # (n/dp, V/m)
 
-    out = shard_map(
+    out = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(P("model", "data"), h_spec),

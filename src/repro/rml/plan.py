@@ -27,10 +27,7 @@ document — reproducing the paper's own follow-up optimizations:
   logical source, share a predicate (PTT dedup state is per predicate, so
   same-predicate rules are *not* independent), or are linked by a join
   dependency (an OJM rule and its parent map).  The groups form the
-  execution DAG ``create_kg`` runs group-by-group — sequentially in one
-  process, and as the scheduling unit for ``rdfize --shards N
-  --shard-workers M`` multi-process builds, where each worker can create a
-  whole group's triples with no cross-worker coordination.
+  execution DAG ``create_kg`` runs group-by-group.
 
 The plan never changes *what* is produced — the executor's output is
 byte-identical with the planner on or off (property-tested) — only how

@@ -1,4 +1,4 @@
-"""Fused scan–join chain for small query batches (the serving fast path).
+"""Fused scan–join chain math for small query batches (the serving fast path).
 
 The general ``repro.serve.exec`` pipeline compiles a whole algebra tree
 and amortizes its per-dispatch constant over thousands of same-signature
@@ -18,19 +18,10 @@ convention:
   and a single ``[n_stages]`` *max-needed* vector — one tiny transfer
   replaces the general path's per-capacity ``needed`` dict sync.
 
-The chain math (:func:`chain_query`) is written once in pure jnp and
-launched two ways:
-
-* :func:`make_batched` with ``use_kernel=False`` — ``vmap`` over the
-  batch, jitted by the caller.  This is the production path on CPU
-  hosts (CI) where Pallas kernels only run interpreted.
-* ``use_kernel=True`` — a Pallas kernel with ``grid=(batch,)``: every
-  program runs one query's whole chain (binary-search range scans plus
-  bind-join expansion) in a single kernel launch, following the
-  ``bucket_dedup`` idiom (full-array operands, one output row block per
-  program).  Selected when :func:`repro.compat.pallas_native` reports a
-  backend that compiles Pallas natively; on CPU it is validated against
-  the reference path under ``interpret=True`` in the tests.
+The chain math (:func:`chain_query`) is written once in pure jnp;
+:func:`make_batched` ``vmap`` s it over the batch and the caller jits
+the result.  XLA compiles that formulation on every backend, so it is
+the one fast path.
 
 All semantics match the general executor operator for operator: the
 same packed-bound encoding (``-1`` wildcard packs below every real id,
@@ -194,7 +185,7 @@ def _bounds(r: ReaderSpec, consts_r, cols, shape):
 
 
 # ---------------------------------------------------------------------------
-# one query's whole chain (pure jnp — shared by both launch strategies)
+# one query's whole chain (pure jnp)
 # ---------------------------------------------------------------------------
 
 
@@ -281,7 +272,7 @@ def chain_query(
 
 
 # ---------------------------------------------------------------------------
-# launch strategies
+# the batched entry point
 # ---------------------------------------------------------------------------
 
 
@@ -306,83 +297,7 @@ def _unpack_qrow(spec: ChainSpec, qrow):
     return qrow[: 3 * R].reshape(R, 3), qrow[3 * R] != 0, qrow[3 * R + 1]
 
 
-def pallas_scan_join(
-    spec: ChainSpec,
-    caps: tuple[int, ...],
-    *args,
-    interpret: bool = True,
-):
-    """The Pallas launch: ``grid=(batch,)``, one program per query, the
-    whole chain (range searches + bind-join expansion + tail) in one
-    kernel.  Store operands are full-array inputs; per-query rows are
-    ``(1, ...)`` blocks indexed by the program id; outputs are one row
-    block per program.  ``interpret=True`` validates on CPU."""
-    from jax.experimental import pallas as pl
-
-    operands, qbuf = _split_args(spec, args)
-    B = qbuf.shape[0]
-    n_readers = len(spec.readers)
-    n_out = len(spec.out_cols)
-    cap = caps[-1]
-
-    def kernel(*refs):
-        in_refs = refs[: 6 * n_readers + 1]
-        out_refs = refs[6 * n_readers + 1 :]
-        ops = tuple(
-            tuple(in_refs[6 * i + t][...] for t in range(6))
-            for i in range(n_readers)
-        )
-        consts_q, qvalid_q, qlimit_q = _unpack_qrow(
-            spec, in_refs[6 * n_readers][0]
-        )
-        outs, n, needed = chain_query(
-            spec, caps, ops, consts_q, qvalid_q, qlimit_q
-        )
-        for t in range(n_out):
-            out_refs[t][0] = outs[t]
-        out_refs[n_out][0] = n
-        out_refs[n_out + 1][0] = needed
-
-    def full(arr):
-        shape = arr.shape
-        return pl.BlockSpec(shape, lambda b, _s=len(shape): (0,) * _s)
-
-    in_specs = [full(a) for pack in operands for a in pack]
-    in_specs += [
-        pl.BlockSpec((1, qrow_width(n_readers)), lambda b: (b, 0)),
-    ]
-    out_specs = [pl.BlockSpec((1, cap), lambda b: (b, 0)) for _ in range(n_out)]
-    out_specs += [
-        pl.BlockSpec((1,), lambda b: (b,)),
-        pl.BlockSpec((1, n_readers), lambda b: (b, 0)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((B, cap), jnp.int32) for _ in range(n_out)
-    ]
-    out_shape += [
-        jax.ShapeDtypeStruct((B,), jnp.int32),
-        jax.ShapeDtypeStruct((B, n_readers), jnp.int32),
-    ]
-    res = pl.pallas_call(
-        kernel,
-        grid=(B,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*[a for pack in operands for a in pack], qbuf)
-    outs = tuple(res[:n_out])
-    n = res[n_out]
-    needed = res[n_out + 1]
-    return outs, n, jnp.max(needed, axis=0)
-
-
-def make_batched(
-    spec: ChainSpec,
-    caps: tuple[int, ...],
-    use_kernel: bool = False,
-    interpret: bool = True,
-):
+def make_batched(spec: ChainSpec, caps: tuple[int, ...]):
     """A jit-able batched entry point for one (chain, capacities) shape.
 
     Takes the flat argument list ``(*reader operands, qbuf[B,
@@ -390,14 +305,6 @@ def make_batched(
     and returns ``(out_cols, n, needed_max)`` with ``needed_max``
     reduced over the batch on device — the caller syncs ONE tiny vector
     to drive capacity feedback."""
-    if use_kernel:
-
-        def batched(*args):
-            return pallas_scan_join(
-                spec, caps, *args, interpret=interpret
-            )
-
-        return batched
 
     def batched(*args):
         operands, qbuf = _split_args(spec, args)

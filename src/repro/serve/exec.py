@@ -1388,7 +1388,11 @@ class Executor:
         vt = value_table(enc) if plan.needs_values else None
 
         n_scans = len(plan.scans)
-        z = jnp.zeros(1, jnp.int32)
+
+        def put(x):
+            return jax.device_put(x, store.device)
+
+        z = put(np.zeros(1, np.int32))
         if store.n_triples == 0:
             # empty base under an active overlay: single-row dummies keep
             # every gather in range; the alive prefix sums (length 1) make
@@ -1423,28 +1427,26 @@ class Executor:
             else:
                 dscan_keys_flat = ((z, z),) * n_scans
             alive_flat = tuple(view.alive(s.order) for s in plan.scans)
-            dn_j = jnp.asarray(view.n_delta, jnp.int32)
+            dn_j = put(np.int32(view.n_delta))
         else:
             ov = None
             dscan_cols_flat = (z,) * (3 * n_scans)
             dscan_keys_flat = ((z, z),) * n_scans
             alive_flat = (z,) * n_scans
-            dn_j = jnp.asarray(0, jnp.int32)
+            dn_j = put(np.int32(0))
         if plan.needs_values:
             vt_arrays = (
                 vt.is_lit, vt.is_num, vt.str_rank, vt.num_rank, vt.order_rank
             )
         else:
-            z = jnp.zeros(1, bool)
-            zi = jnp.zeros(1, jnp.int32)
-            vt_arrays = (z, z, zi, zi, zi)
+            zb = put(np.zeros(1, bool))
+            vt_arrays = (zb, zb, z, z, z)
 
         floors = self._floors.setdefault(plan.sig, {})
         caps = _initial_caps(plan, floors)
-        consts_j = jnp.asarray(consts)
-        fops_j = jnp.asarray(fops)
-        qvalid_j = jnp.asarray(qvalid)
-        qlimit_j = jnp.asarray(limits)
+        consts_j, fops_j, qvalid_j, qlimit_j = put(
+            (consts, fops, qvalid, limits)
+        )
         reg = get_registry()
         tracer = get_tracer()
         label = plan_label(plan.sig)
@@ -1535,10 +1537,12 @@ class Executor:
         for text in texts:
             try:
                 q = A.parse_select(text)
-                self.execute(self.plan(q), [q])
-                warmed += 1
-            except Exception:  # a shape the store can't serve: skip it
+            except ValueError:  # a predicate the query grammar can't spell
                 continue
+            # compile and device errors propagate: a server that cannot
+            # run its dominant shapes must not start
+            self.execute(self.plan(q), [q])
+            warmed += 1
         return warmed
 
 

@@ -5,22 +5,21 @@ tree and ships ~30 operand leaves per dispatch; that constant is noise
 at batch 4096 and dominant at batch 1.  For the plan shapes that carry
 interactive traffic — a ``Scan → BindJoin*`` chain of up to three
 pattern readers (see :func:`repro.serve.plan.fastpath_chain`) — this
-module dispatches through :mod:`repro.kernels.scan_join` instead, with
+module dispatches through :mod:`repro.serve.chain` instead, with
 every per-dispatch cost stripped:
 
 * the chain is resolved at build time into a static
-  :class:`~repro.kernels.scan_join.ChainSpec` (index orders, constant /
+  :class:`~repro.serve.chain.ChainSpec` (index orders, constant /
   left-bound / wildcard sources, projection columns), so dispatch does
   no plan walking;
 * per-query inputs are written into **grow-only staging buffers** kept
-  per batch pad — no per-dispatch allocation — and donated to the
-  compiled function on accelerator backends;
+  per batch pad — no per-dispatch allocation — and reach the device as
+  one transfer;
 * the per-capacity ``needed`` dict (one device→host sync per operator
   in the general path) collapses to a single ``[n_readers]`` max
   vector reduced on device;
-* on backends that compile Pallas natively the whole batch runs as one
-  fused ``grid=(batch,)`` kernel; CPU hosts use the jitted vmapped
-  reference formulation of the same chain math.
+* the whole batch runs as one jitted, vmapped chain program, on every
+  backend.
 
 The capacity-feedback contract is shared with the general executor:
 the same ``scan{id}`` / ``bindC{id}`` capacity names against the same
@@ -37,14 +36,12 @@ from __future__ import annotations
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.core.hashset import next_pow2
-from repro.kernels import scan_join as K
 from repro.kg.store import ORDERS
 from repro.obs import get_registry, get_tracer
+from repro.serve import chain as K
 from repro.serve import plan as P
 
 # batches this small dispatch through the fused chain; larger ones are
@@ -152,9 +149,6 @@ class SigFastPath:
         self.label = plan_label(plan.sig)
         self._staging: dict[int, np.ndarray] = {}
         self._compiled: dict[tuple, callable] = {}
-        # one fused kernel on native-Pallas backends; the jitted vmapped
-        # reference chain on CPU (where Pallas only interprets)
-        self._use_kernel = compat.pallas_native()
 
     def _get_fn(self, bpad: int, caps: tuple[int, ...]):
         key = (bpad, caps)
@@ -165,15 +159,9 @@ class SigFastPath:
             return fn
         reg.inc("exec.pipeline_cache_miss")
         reg.inc("exec.fastpath_compiles")
-        batched = K.make_batched(
-            self.spec, caps, use_kernel=self._use_kernel, interpret=False
-        )
-        if self._use_kernel:
-            # donate the per-query device buffer: its storage is dead
-            # after the call (the host staging buffer persists)
-            fn = jax.jit(batched, donate_argnums=(len(self.operands),))
-        else:  # CPU jit does not implement donation (warns per call)
-            fn = jax.jit(batched)
+        # no donation: no output has the staging row's shape, so XLA
+        # cannot reuse its buffer (the TPU compiler says so, per compile)
+        fn = jax.jit(K.make_batched(self.spec, caps))
         self._compiled[key] = fn
         return fn
 
@@ -216,7 +204,9 @@ class SigFastPath:
         for round_i in range(_MAX_GROW_ROUNDS):
             t0 = time.perf_counter_ns()
             fn = self._get_fn(bpad, tuple(caps))
-            outs, n, needed_max = fn(*self.operands, jnp.asarray(qbuf))
+            outs, n, needed_max = fn(
+                *self.operands, jax.device_put(qbuf, ex.store.device)
+            )
             ex.dispatches += 1
             need = np.asarray(needed_max)
             grown = False

@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -128,15 +129,19 @@ def value_table(store: TripleStore) -> ValueTable:
     within = np.where(
         ~is_lit, tid, np.where(is_num, num_rank, str_rank)
     ).astype(np.int32)
-    perm = jnp.lexsort((jnp.asarray(tid), jnp.asarray(within), jnp.asarray(cls)))
-    arange = jnp.arange(T, dtype=jnp.int32)
-    order_rank = jnp.zeros(T, jnp.int32).at[perm].set(arange)
+
+    def put(x):
+        return jax.device_put(x, store.device)
+
+    perm = jnp.lexsort((put(tid), put(within), put(cls)))
+    arange = put(np.arange(T, dtype=np.int32))
+    order_rank = jnp.zeros_like(arange).at[perm].set(arange)
     order_is_tid = bool(jnp.all(perm == arange))
     table = ValueTable(
-        is_lit=jnp.asarray(is_lit),
-        is_num=jnp.asarray(is_num),
-        str_rank=jnp.asarray(str_rank),
-        num_rank=jnp.asarray(num_rank),
+        is_lit=put(is_lit),
+        is_num=put(is_num),
+        str_rank=put(str_rank),
+        num_rank=put(num_rank),
         order_rank=order_rank,
         order_is_tid=order_is_tid,
         str_uniq=str_uniq,

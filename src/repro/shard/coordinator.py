@@ -40,6 +40,8 @@ import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
 
+import jax
+
 from repro.api import LocalSession, Session, QueryResult
 from repro.api.errors import KGError, ProtocolError, error_from_reply
 from repro.obs import MetricsRegistry, get_registry
@@ -502,6 +504,14 @@ class ShardGroup:
 # ---------------------------------------------------------------------------
 
 
+def shard_device(i: int):
+    """The device shard ``i`` serves from: shards spread round-robin
+    over the host's local devices, one chip each on a multi-chip host;
+    ``None`` (JAX's default device) where there is only one."""
+    devices = jax.local_devices()
+    return devices[i % len(devices)] if len(devices) > 1 else None
+
+
 def open_shard_group(
     manifest_path: str,
     read_only: bool = False,
@@ -521,15 +531,15 @@ def open_shard_group(
     if m["n_shards"] + 2 > cap:
         persist.set_open_store_cache_size(m["n_shards"] + 2)
     sessions = []
-    for entry in m["shards"]:
+    for i, entry in enumerate(m["shards"]):
         if read_only:
-            sessions.append(
-                LocalSession(
-                    persist.open_store(entry["abs_path"]), read_only=True
-                )
-            )
+            store = persist.open_store(entry["abs_path"])
+            store.place(shard_device(i))
+            sessions.append(LocalSession(store, read_only=True))
         else:
-            sessions.append(LocalSession(persist.load_chain(entry["abs_path"])))
+            live = persist.load_chain(entry["abs_path"])
+            live.base.place(shard_device(i))
+            sessions.append(LocalSession(live))
     return ShardGroup(
         [_LocalBackend(s) for s in sessions],
         registry=registry,
@@ -577,12 +587,12 @@ def spawn_shard_servers(
     if m["n_shards"] + 2 > cap:
         persist.set_open_store_cache_size(m["n_shards"] + 2)
     servers = []
-    for entry in m["shards"]:
+    for i, entry in enumerate(m["shards"]):
+        store = persist.open_store(entry["abs_path"]).place(shard_device(i))
         if read_only:
-            served = persist.open_store(entry["abs_path"])
+            served = store
             kg_path = None
         else:
-            store = persist.open_store(entry["abs_path"])
             served = LiveStore(store)
             kg_path = entry["abs_path"]
         servers.append(

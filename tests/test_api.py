@@ -123,6 +123,40 @@ def test_remote_typed_errors():
     assert issubclass(api.ProtocolError, ConnectionError)
 
 
+_CLIENT = """
+import sys
+from repro.launch import serve
+sys.argv = ["serve", "--connect", {addr!r}, "--query", {q!r}]
+serve.main()
+from jax._src import xla_bridge
+sys.exit(3 if xla_bridge.backends_are_initialized() else 0)
+"""
+
+
+def test_serve_client_mode_leaves_jax_uninitialised():
+    """The CLI's client mode runs beside a server that holds the chip, so
+    it must never bring up a JAX backend of its own."""
+    import os
+    import subprocess
+    import sys
+
+    store = rand_store(6, 25)
+    srv = KGServer(store, port=0, linger_ms=1.0, log=False).start()
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    try:
+        script = _CLIENT.format(addr=f"127.0.0.1:{srv.port}",
+                                q="SELECT * WHERE { ?s <http://ex/p0> ?o }")
+        proc = subprocess.run(
+            [sys.executable, "-c", script], capture_output=True, text=True,
+            env=dict(os.environ, PYTHONPATH=src), timeout=120,
+        )
+    finally:
+        srv.stop()
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert '"vars"' in proc.stdout
+
+
 def test_connect_path_arms(tmp_path):
     store = rand_store(7, 30)
     path = str(tmp_path / "t.kgz")

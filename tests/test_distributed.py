@@ -19,9 +19,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import hashing, distributed
 from collections import defaultdict
 
-from repro.compat import compat_make_mesh
-
-mesh = compat_make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh(
+    (4, 2), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2
+)
 sh = NamedSharding(mesh, P(("data", "model")))
 rng = np.random.default_rng(0)
 out = {}

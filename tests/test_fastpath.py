@@ -1,7 +1,6 @@
 """The small-batch fast path: fused scan-join chain vs the general
 executor (bit-identical rows over property-generated queries), overlay
-fallback, the Pallas kernel formulation vs the vmapped reference,
-signature warm-up, and the adaptive micro-batch linger."""
+fallback, signature warm-up, and the adaptive micro-batch linger."""
 
 import numpy as np
 import pytest
@@ -11,11 +10,9 @@ try:
 except ImportError:  # test image without hypothesis: seeded-example fallback
     from _hypothesis_shim import given, settings, st
 
-from repro.kernels import scan_join as K
 from repro.kg.store import TripleStore
 from repro.live.delta import LiveStore
 from repro.obs import get_registry
-from repro.serve import fastpath as FP
 from repro.serve import parse_select
 from repro.serve.exec import get_executor
 from repro.serve.server import _AdaptiveLinger
@@ -137,47 +134,6 @@ def test_overlay_falls_back_to_general():
     assert reg.counter("exec.fastpath_dispatches").value == before
     assert res.n(0) == base_n + 1
     assert ("<http://ex/new>", '"live"') in res.rows(0)
-
-
-def test_kernel_matches_reference():
-    """The Pallas grid kernel (interpret mode on CPU) and the vmapped
-    reference compute bit-identical outputs from one ChainSpec."""
-    # skew predicate cardinalities so the planner anchors on the rare
-    # p0 and bind-joins the common p1 (scan.est > left.est): a genuine
-    # 2-reader chain, not a merge join
-    triples = [(f"<http://ex/s{i}>", "<http://ex/p1>", f'"v{i % 7}"')
-               for i in range(40)]
-    triples += [(f"<http://ex/s{i}>", "<http://ex/p0>", '"anchor"')
-                for i in range(5)]
-    store = TripleStore.from_ntriples(sorted(set(triples)))
-    ex = get_executor(store)
-    q = parse_select(
-        "SELECT * WHERE { ?s <http://ex/p0> ?a . ?s <http://ex/p1> ?b }"
-    )
-    plan = ex.plan(q)
-    fp = FP.build(ex, plan)
-    assert fp is not None and len(fp.spec.readers) == 2
-    caps = tuple(max(c, 64) for c in fp.base_caps)
-    ref = K.make_batched(fp.spec, caps, use_kernel=False)
-    ker = K.make_batched(fp.spec, caps, use_kernel=True, interpret=True)
-    rng = np.random.default_rng(0)
-    bsz = 4
-    w = K.qrow_width(len(fp.spec.readers))
-    qbuf = np.full((bsz, w), -1, np.int32)
-    for i in range(bsz):
-        consts = np.full((len(fp.spec.readers), 3), -2, np.int32)
-        # vary the subject anchor: valid ids, an unknown id, wildcards
-        consts[:, 0] = [-2, 0, int(rng.integers(0, store.n_terms)),
-                        10 ** 6][i % 4]
-        qbuf[i, : 3 * len(fp.spec.readers)] = consts.reshape(-1)
-        qbuf[i, 3 * len(fp.spec.readers)] = 1
-        qbuf[i, 3 * len(fp.spec.readers) + 1] = -1
-    r_outs, r_n, r_need = ref(*fp.operands, qbuf)
-    k_outs, k_n, k_need = ker(*fp.operands, qbuf)
-    assert np.array_equal(np.asarray(r_n), np.asarray(k_n))
-    assert np.array_equal(np.asarray(r_need), np.asarray(k_need))
-    for a, b in zip(r_outs, k_outs):
-        assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_warmup_precompiles_signatures():

@@ -228,25 +228,6 @@ def test_planner_identity_on_generator_testbeds(kind, tmp_path):
                block_rows=128) == ref
 
 
-def test_planner_identity_sharded(tmp_path):
-    """Group-parallel sharded build == monolithic sharded build, down to
-    the shard .kgz bytes."""
-    from repro.shard.ingest import ingest_mapping_sharded, shard_store
-
-    _write_wide_testbed(str(tmp_path), n_genes=80, n_muts=150)
-    doc = parser.parse(WIDE_TTL)
-    mono = create_kg(doc, data_root=str(tmp_path), mapping_plan=False)
-    shard_store(mono.to_store(), str(tmp_path / "mono.shards.json"), 2)
-    ingest_mapping_sharded(
-        WIDE_TTL, str(tmp_path), str(tmp_path / "grp.shards.json"), 2,
-        workers=0, engine_opts=dict(stream=True, block_rows=64),
-    )
-    for i in range(2):
-        a = (tmp_path / f"mono.shard{i}.kgz").read_bytes()
-        b = (tmp_path / f"grp.shard{i}.kgz").read_bytes()
-        assert a == b
-
-
 def test_factoring_actually_happens(tmp_path):
     """plan.factored_rows counts cache-served slots; output is unchanged."""
     from repro import obs

@@ -324,20 +324,23 @@ def test_manifest_roundtrip_and_validation(tmp_path):
     assert not persist.is_manifest(other)
 
 
-def test_multiprocess_ingest_matches_serial(tmp_path):
-    store = rand_store(31, 40)
-    triples = decoded_triples(store)
-    serial = str(tmp_path / "a.shards.json")
-    parallel = str(tmp_path / "b.shards.json")
-    ingest_sharded(triples, serial, 2, workers=0)
-    ingest_sharded(triples, parallel, 2, workers=2)  # spawned pool
-    ms, mp = persist.load_manifest(serial), persist.load_manifest(parallel)
-    for es, ep in zip(ms["shards"], mp["shards"]):
-        assert es["n_triples"] == ep["n_triples"]
-        assert es["n_terms"] == ep["n_terms"]
-        a = persist.open_store(es["abs_path"])
-        b = persist.open_store(ep["abs_path"])
-        assert decoded_triples(a) == decoded_triples(b)
+def test_store_placement_follows_overlay_and_compaction():
+    """A placed store keeps its device through the live overlay and the
+    compacted rebuild, and refuses a move once it holds device arrays."""
+    import jax
+
+    from repro.live.delta import LiveStore
+
+    dev = jax.devices()[0]
+    store = rand_store(3, 30).place(dev)
+    assert store.device_cols("spo")[0].device == dev
+    live = LiveStore(store)
+    live.insert([("<http://ex/new>", PREDS[0], '"x"')])
+    view = live.view()
+    assert view.delta.device == dev and view.alive("spo").device == dev
+    assert live.compact().device == dev
+    with pytest.raises(ValueError, match="before its first query"):
+        store.place(None)
 
 
 # --------------------------------------------------------------------------

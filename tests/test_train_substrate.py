@@ -99,9 +99,7 @@ def test_checkpoint_elastic_restore_across_mesh(tmp_path):
     path device_put's through NamedSharding)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.compat import compat_make_mesh
-
-    mesh = compat_make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
     tree = {"w": jnp.arange(8, dtype=jnp.float32)}
     path = os.path.join(tmp_path, "step_5")
     checkpoint.save(path, tree, step=5)
