@@ -15,6 +15,15 @@ Engines:
 
 Both produce identical knowledge graphs (asserted in tests); they differ
 only in operation count / wall-time, which is the paper's claim.
+
+Instrumentation (``repro.obs``), on the thread that runs the engine:
+spans ``ingest.read`` (source rows), ``ingest.encode`` (term columns to
+dictionary ids), ``ingest.pjtt_build``, ``ingest.size`` (the sizing
+pre-pass), ``ingest.step`` per batch with its children ``ingest.sync``
+(every blocking read of the step's outputs) and ``ingest.compact`` (the
+host-side selection of the emitted rows), and ``ingest.assemble``; the
+counters ``ingest.host_syncs`` (blocking device-to-host reads) and
+``ingest.replays`` (predicates replayed after a PTT overflow).
 """
 
 from __future__ import annotations
@@ -27,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import hashing, naive, pjtt, planner
 from repro.core import hashset
 from repro.core.hashset import next_pow2
@@ -216,12 +226,24 @@ class KGResult:
         return out
 
 
+def _assemble(exec_plan, triples_out) -> dict:
+    """Each predicate's appended chunks as one array per column, in the op
+    plan's predicate order whatever the group scheduling: the written KG
+    is byte-identical planner-on/off."""
+    with obs.span("ingest.assemble"):
+        return {
+            pred: {
+                k: np.concatenate(v) if v else np.zeros(0, np.int32)
+                for k, v in triples_out[pred].items()
+            }
+            for pred in exec_plan.by_predicate
+        }
+
+
 def _plan_gauges(mplan) -> None:
     """Publish the mapping plan's shape into ``repro.obs`` (plan.* rows
     in the metrics catalog)."""
-    from repro.obs import get_registry
-
-    reg = get_registry()
+    reg = obs.get_registry()
     reg.gauge("plan.groups").set(len(mplan.groups))
     reg.gauge("plan.sources").set(len(mplan.sources))
     reg.gauge("plan.shared_terms").set(len(mplan.shared))
@@ -238,6 +260,29 @@ def _sources_by_key(doc: MappingDocument) -> dict:
 
 # shared with the repro.kg decode path: full N-Triples escaping, not just `"`
 _render = render_term
+
+_END = object()
+
+
+def _spanned(name: str, blocks):
+    """``blocks`` with each fetch of the next block timed as span
+    ``name``; the span closes before the block is handed on, so it never
+    holds a ``yield``."""
+    it = iter(blocks)
+    while True:
+        with obs.span(name):
+            block = next(it, _END)
+        if block is _END:
+            return
+        yield block
+
+
+def _touch_counters() -> None:
+    """Create the engine's counters, so that a run without a sync or a
+    replay reads 0 rather than nothing."""
+    reg = obs.get_registry()
+    reg.counter("ingest.host_syncs")
+    reg.counter("ingest.replays")
 
 
 # --------------------------------------------------------------------------
@@ -287,6 +332,7 @@ class Engine:
         maps source key ('csv:child.csv') -> columnar dict."""
         t0 = time.perf_counter()
         cfg = self.config
+        _touch_counters()
         mplan = None
         if cfg.mapping_plan:
             from repro.rml.plan import build_plan
@@ -316,7 +362,8 @@ class Engine:
             if src is None:
                 fmt, path, iterator = planner.parse_source_key(source_key)
                 src = LogicalSource(path=path, fmt=fmt, iterator=iterator)
-            return cache.get(src)
+            with obs.span("ingest.read", source=source_key):
+                return cache.get(src)
 
         # ---- encode the value columns each op needs (once per column set)
         value_cache: dict[tuple, np.ndarray] = {}
@@ -324,26 +371,25 @@ class Engine:
         def values_for(source_key: str, columns: tuple[str, ...]) -> np.ndarray:
             key = (source_key, columns)
             if key not in value_cache:
-                value_cache[key] = self._term_values(
-                    dct, get_table(source_key), columns
-                )
+                table = get_table(source_key)
+                with obs.span("ingest.encode", source=source_key):
+                    value_cache[key] = self._term_values(dct, table, columns)
             return value_cache[key]
 
         # ---- build PJTTs once per (parent map, join column)
         indexes: dict[str, tuple] = {}
-        parent_meta: dict[str, tuple[int, np.ndarray]] = {}
         for pkey, (psrc, pcol, _ppat, pcols) in exec_plan.pjtt_builds.items():
             pkeys = values_for(psrc, (pcol,))
             psubj = values_for(psrc, pcols)
-            kd = jnp.asarray(pkeys)
-            sd = jnp.asarray(psubj)
-            if cfg.engine == "naive":
-                indexes[pkey] = (kd, sd)  # raw arrays for the nested loop
-            elif cfg.join_strategy == "hash":
-                indexes[pkey] = _build_hash(kd, sd)
-            else:
-                indexes[pkey] = _build_sorted(kd, sd)
-            parent_meta[pkey] = (len(pkeys), np.asarray(pkeys))
+            with obs.span("ingest.pjtt_build", pjtt=pkey):
+                kd = jnp.asarray(pkeys)
+                sd = jnp.asarray(psubj)
+                if cfg.engine == "naive":
+                    indexes[pkey] = (kd, sd)  # raw arrays for the nested loop
+                elif cfg.join_strategy == "hash":
+                    indexes[pkey] = _build_hash(kd, sd)
+                else:
+                    indexes[pkey] = _build_sorted(kd, sd)
 
         # ---- per-predicate candidate estimate -> PTT capacity
         stats: dict[str, PredicateStats] = {}
@@ -358,17 +404,15 @@ class Engine:
                 if op.kind == "OJM":
                     # exact |N_p| and max span from the sorted parent keys;
                     # sizes the PTT and the padded-ragged probe width
-                    skeys = jnp.sort(
-                        jnp.asarray(
-                            values_for(op.parent_source_key, (op.parent_join_column,))
-                        )
-                    )
-                    ck = jnp.asarray(
-                        values_for(op.source_key, (op.join_child_column,))
-                    )
-                    tot, mx = _span_stats(skeys, ck)
-                    op_spans[i] = (int(tot), int(mx))
-                    total += int(tot)
+                    pk = values_for(op.parent_source_key, (op.parent_join_column,))
+                    ck = values_for(op.source_key, (op.join_child_column,))
+                    with obs.span("ingest.size", op=i):
+                        skeys = jnp.sort(jnp.asarray(pk))
+                        tot, mx = _span_stats(skeys, jnp.asarray(ck))
+                        tot, mx = int(tot), int(mx)
+                        obs.get_registry().inc("ingest.host_syncs", 2)
+                    op_spans[i] = (tot, mx)
+                    total += tot
                 else:
                     op_spans[i] = (n_child, 1)
                     total += n_child
@@ -386,8 +430,6 @@ class Engine:
             ]
         else:
             schedule = [(None, list(exec_plan.by_predicate.items()))]
-        from repro import obs
-
         for g, pred_items in schedule:
             span_args = {"group": g.index} if g is not None else {}
             with obs.span("plan_group", cat="plan", **span_args):
@@ -403,18 +445,9 @@ class Engine:
                         triples_out, dct, pred_items=pred_items,
                     )
 
-        # emit in the op plan's predicate order regardless of group
-        # scheduling: the written KG is byte-identical planner-on/off
-        final = {
-            pred: {
-                k: np.concatenate(v) if v else np.zeros(0, np.int32)
-                for k, v in triples_out[pred].items()
-            }
-            for pred in exec_plan.by_predicate
-        }
         return KGResult(
             dictionary=dct,
-            triples=final,
+            triples=_assemble(exec_plan, triples_out),
             stats=stats,
             wall_time_s=time.perf_counter() - t0,
             engine=cfg.engine,
@@ -428,6 +461,10 @@ class Engine:
         """Push one fixed-shape padded batch through the jitted step for
         ``op``; appends emitted triples to ``out`` and accumulates ``st``.
         Returns ``(hi, lo, overflowed)``."""
+        with obs.span("ingest.step", op=op.kind, rows=len(batch.valid)):
+            return self._step(op, spat, pid, opat, hi, lo, batch, index, K, out, st)
+
+    def _step(self, op, spat, pid, opat, hi, lo, batch, index, K, out, st):
         valid = jnp.asarray(batch.valid)
         sv = jnp.asarray(batch.arrays["subj"])
         if op.kind == "OJM":
@@ -442,35 +479,38 @@ class Engine:
                     hi, lo, index.tkey, index.tstart, index.tcount,
                     index.ssubj, spat, sv, pid, opat, K, ck, valid,
                 )
-            if bool(trunc):
-                raise RuntimeError(
-                    f"PJTT span exceeded max_matches={K}; "
-                    "re-run with a larger max_matches"
-                )
-            is_new_np = np.asarray(is_new)
-            v_np = np.asarray(v)
-            st.n_candidates += int(v_np.sum())
-            emit = is_new_np & v_np
-            rows, ks = np.nonzero(emit)
-            sv_np = np.asarray(batch.arrays["subj"])
-            ps_np = np.asarray(psubj)
-            out["subj_val"].append(sv_np[rows].astype(np.int32))
-            out["obj_val"].append(ps_np[rows, ks].astype(np.int32))
-            n_emit = len(rows)
+            outputs = (trunc, is_new, v, psubj, ovf)
         else:
             ov = jnp.asarray(batch.arrays["obj"])
             hi, lo, is_new, ovf = _dedup_step(
                 hi, lo, spat, sv, pid, opat, ov, valid
             )
-            is_new_np = np.asarray(is_new)
-            st.n_candidates += int(batch.valid.sum())
-            rows = np.nonzero(is_new_np & batch.valid)[0]
-            out["subj_val"].append(batch.arrays["subj"][rows].astype(np.int32))
-            out["obj_val"].append(batch.arrays["obj"][rows].astype(np.int32))
+            outputs = (is_new, ovf)
+        with obs.span("ingest.sync"):
+            got = [np.asarray(x) for x in outputs]  # one blocking read each
+            obs.get_registry().inc("ingest.host_syncs", len(got))
+        with obs.span("ingest.compact"):
+            if op.kind == "OJM":
+                trunc, is_new, v, psubj, ovf = got
+                if trunc:
+                    raise RuntimeError(
+                        f"PJTT span exceeded max_matches={K}; "
+                        "re-run with a larger max_matches"
+                    )
+                st.n_candidates += int(v.sum())
+                rows, ks = np.nonzero(is_new & v)
+                obj = psubj[rows, ks]
+            else:
+                is_new, ovf = got
+                st.n_candidates += int(batch.valid.sum())
+                rows = np.nonzero(is_new & batch.valid)[0]
+                obj = batch.arrays["obj"][rows]
             n_emit = len(rows)
-        out["subj_pat"].append(np.full(n_emit, spat, np.int32))
-        out["obj_pat"].append(np.full(n_emit, opat, np.int32))
-        st.n_unique += n_emit
+            out["subj_val"].append(batch.arrays["subj"][rows].astype(np.int32))
+            out["obj_val"].append(obj.astype(np.int32))
+            out["subj_pat"].append(np.full(n_emit, spat, np.int32))
+            out["obj_pat"].append(np.full(n_emit, opat, np.int32))
+            st.n_unique += n_emit
         return hi, lo, bool(ovf)
 
     # -- optimized engine ------------------------------------------------------
@@ -530,6 +570,7 @@ class Engine:
                 if not overflow:
                     triples_out[pred] = out
                     break
+                obs.get_registry().inc("ingest.replays")
                 cap *= 2  # replay this predicate with a bigger table
 
     # -- streamed optimized engine (repro.stream) ------------------------------
@@ -553,7 +594,6 @@ class Engine:
         live only for that group."""
         import os
 
-        from repro import obs
         from repro.stream import Dataset, read_source
         from repro.stream.block import Block
         from repro.stream.datasource import is_sharded_path
@@ -561,6 +601,7 @@ class Engine:
         cfg = self.config
         exec_plan = mplan.exec_plan if mplan is not None else planner.plan(doc)
         reg = obs.get_registry()
+        _touch_counters()
         dct = Dictionary()
         block_rows = cfg.block_rows
         # block_rows bounds I/O granularity; batch_size still bounds the
@@ -660,7 +701,7 @@ class Engine:
                 continue
             seen: set = set()
             n = 0
-            for block in dataset_for(skey).iter_blocks(prefetch):
+            for block in _spanned("ingest.read", dataset_for(skey).iter_blocks(prefetch)):
                 seen |= set(block.schema)
                 n += block.n_rows
             row_counts[skey] = n
@@ -694,17 +735,25 @@ class Engine:
                 )
                 chunks: dict[tuple, list] = {cols: [] for cols in colsets}
                 n = 0
-                for block in ds.iter_blocks(prefetch):
+                with obs.span("ingest.encode", source=skey):
+                    for block in _spanned("ingest.read", ds.iter_blocks(prefetch)):
+                        for cols in colsets:
+                            chunks[cols].append(dct.encode(derived(block, cols)))
+                        n += block.n_rows
                     for cols in colsets:
-                        chunks[cols].append(dct.encode(derived(block, cols)))
-                    n += block.n_rows
-                for cols in colsets:
-                    value_cache[(skey, cols)] = (
-                        np.concatenate(chunks[cols])
-                        if chunks[cols]
-                        else np.zeros(0, np.int32)
-                    )
+                        value_cache[(skey, cols)] = (
+                            np.concatenate(chunks[cols])
+                            if chunks[cols]
+                            else np.zeros(0, np.int32)
+                        )
                 row_counts[skey] = n
+
+        def count_rows(source_key: str) -> int:
+            n = row_counts.get(source_key)
+            if n is None:
+                with obs.span("ingest.read", source=source_key):
+                    n = row_counts[source_key] = dataset_for(source_key).count()
+            return n
 
         def op_blocks(op):
             """Planner-on block stream for one op: slots whose term column
@@ -732,10 +781,7 @@ class Engine:
                 if cached:
                     length = len(next(iter(cached.values())))
                 else:
-                    length = row_counts.get(op.source_key)
-                    if length is None:
-                        length = dataset_for(op.source_key).count()
-                        row_counts[op.source_key] = length
+                    length = count_rows(op.source_key)
                 for start in range(0, length, block_rows):
                     end = min(start + block_rows, length)
                     cols_out = {}
@@ -754,16 +800,17 @@ class Engine:
                 *needed, fill=fill_of(op.source_key), pushdown=True
             )
             offset = 0
-            for block in ds.iter_blocks(prefetch):
+            for block in _spanned("ingest.read", ds.iter_blocks(prefetch)):
                 m = block.n_rows
                 cols_out = {}
-                for name, cols in slots:
-                    if name in cached:
-                        cols_out[name] = cached[name][offset:offset + m]
-                    elif not cols:
-                        cols_out[name] = np.zeros(m, np.int32)
-                    else:
-                        cols_out[name] = dct.encode(derived(block, cols))
+                with obs.span("ingest.encode", source=op.source_key):
+                    for name, cols in slots:
+                        if name in cached:
+                            cols_out[name] = cached[name][offset:offset + m]
+                        elif not cols:
+                            cols_out[name] = np.zeros(m, np.int32)
+                        else:
+                            cols_out[name] = dct.encode(derived(block, cols))
                 if cached:
                     reg.inc("plan.factored_rows", m * len(cached))
                 offset += m
@@ -774,54 +821,47 @@ class Engine:
         parent_counts: dict[str, int] = {}
         sorted_parent_keys: dict[str, np.ndarray] = {}
 
+        def parent_columns(psrc, pcol, pcols):
+            """The encoded join-key and subject columns of a parent."""
+            kc = value_cache.get((psrc, (pcol,)))
+            sc = value_cache.get((psrc, tuple(pcols)))
+            if kc is not None and (sc is not None or not pcols):
+                # both columns already factored: build from the cache
+                reg.inc("plan.factored_rows", 2 * len(kc))
+                return kc, sc if sc is not None else np.zeros(len(kc), np.int32)
+            needed = tuple(dict.fromkeys((pcol,) + tuple(pcols)))
+
+            def to_index_columns(block: Block) -> Block:
+                return Block(
+                    {"key": block.columns[pcol], "subj": derived(block, pcols)}
+                )
+
+            ds = dataset_for(psrc).project(
+                *needed, fill=fill_of(psrc), pushdown=mplan is not None,
+            )
+            ds = ds.map_blocks(to_index_columns).encode(dct)
+            kchunks, schunks = [], []
+            for block in ds.iter_blocks(prefetch):
+                kchunks.append(block.columns["key"])
+                schunks.append(block.columns["subj"])
+            if not kchunks:
+                return np.zeros(0, np.int32), np.zeros(0, np.int32)
+            return np.concatenate(kchunks), np.concatenate(schunks)
+
         def build_pjtts(pjtt_items) -> None:
             for pkey, (psrc, pcol, _ppat, pcols) in pjtt_items:
-                kc = value_cache.get((psrc, (pcol,)))
-                sc = value_cache.get((psrc, tuple(pcols)))
-                if kc is not None and (sc is not None or not pcols):
-                    # both columns already factored: build from the cache
-                    pkeys = kc
-                    psubj = (
-                        sc if sc is not None else np.zeros(len(kc), np.int32)
-                    )
-                    reg.inc("plan.factored_rows", 2 * len(pkeys))
-                else:
-                    needed = tuple(dict.fromkeys((pcol,) + tuple(pcols)))
-
-                    def to_index_columns(
-                        block: Block, pcol=pcol, pcols=pcols
-                    ) -> Block:
-                        return Block(
-                            {
-                                "key": block.columns[pcol],
-                                "subj": derived(block, pcols),
-                            }
-                        )
-
-                    ds = dataset_for(psrc).project(
-                        *needed, fill=fill_of(psrc),
-                        pushdown=mplan is not None,
-                    )
-                    ds = ds.map_blocks(to_index_columns).encode(dct)
-                    kchunks, schunks = [], []
-                    for block in ds.iter_blocks(prefetch):
-                        kchunks.append(block.columns["key"])
-                        schunks.append(block.columns["subj"])
-                    pkeys = (
-                        np.concatenate(kchunks) if kchunks
-                        else np.zeros(0, np.int32)
-                    )
-                    psubj = (
-                        np.concatenate(schunks) if schunks
-                        else np.zeros(0, np.int32)
-                    )
-                kd, sd = jnp.asarray(pkeys), jnp.asarray(psubj)
-                if cfg.join_strategy == "hash":
-                    indexes[pkey] = _build_hash(kd, sd)
-                else:
-                    indexes[pkey] = _build_sorted(kd, sd)
+                # the scan's blocks come out encoded: its reads count here
+                with obs.span("ingest.encode", source=psrc):
+                    pkeys, psubj = parent_columns(psrc, pcol, pcols)
+                with obs.span("ingest.pjtt_build", pjtt=pkey):
+                    kd, sd = jnp.asarray(pkeys), jnp.asarray(psubj)
+                    if cfg.join_strategy == "hash":
+                        indexes[pkey] = _build_hash(kd, sd)
+                    else:
+                        indexes[pkey] = _build_sorted(kd, sd)
+                with obs.span("ingest.size", pjtt=pkey):
+                    sorted_parent_keys[pkey] = np.sort(pkeys)
                 parent_counts[pkey] = len(pkeys)
-                sorted_parent_keys[pkey] = np.sort(np.asarray(pkeys))
                 row_counts[psrc] = len(pkeys)
 
         # ---- sizing pre-pass: exact |N_p| and max span, streamed
@@ -831,55 +871,54 @@ class Engine:
 
         def size_predicates(pred_list) -> None:
             for pred in pred_list:
-                op_idxs = exec_plan.by_predicate[pred]
-                total = 0
-                stats[pred] = PredicateStats(
-                    kind=exec_plan.ops[op_idxs[0]].kind
-                )
-                for i in op_idxs:
-                    op = exec_plan.ops[i]
-                    if op.kind == "OJM":
-                        spk = sorted_parent_keys[op.pjtt_key]
-                        ck_all = value_cache.get(
-                            (op.source_key, (op.join_child_column,))
-                        )
-                        if ck_all is not None:
-                            # factored child key: span stats with no re-read
-                            cnt = np.searchsorted(spk, ck_all, side="right") \
-                                - np.searchsorted(spk, ck_all, side="left")
-                            tot = int(cnt.sum()) if len(cnt) else 0
-                            mx = int(cnt.max()) if len(cnt) else 0
-                            row_counts[op.source_key] = len(ck_all)
-                        else:
-                            tot = mx = n = 0
-                            ds = (
-                                dataset_for(op.source_key)
-                                .project(
-                                    op.join_child_column,
-                                    fill=fill_of(op.source_key),
-                                    pushdown=mplan is not None,
-                                )
-                                .encode(dct)
-                            )
-                            for block in ds.iter_blocks(prefetch):
-                                ck = block.columns[op.join_child_column]
-                                cnt = np.searchsorted(spk, ck, side="right") \
-                                    - np.searchsorted(spk, ck, side="left")
-                                if len(cnt):
-                                    tot += int(cnt.sum())
-                                    mx = max(mx, int(cnt.max()))
-                                n += block.n_rows
-                            row_counts[op.source_key] = n
-                        op_spans[i] = (tot, mx)
-                        total += tot
+                with obs.span("ingest.size", pred=pred):
+                    size_predicate(pred)
+
+        def size_predicate(pred) -> None:
+            op_idxs = exec_plan.by_predicate[pred]
+            total = 0
+            stats[pred] = PredicateStats(kind=exec_plan.ops[op_idxs[0]].kind)
+            for i in op_idxs:
+                op = exec_plan.ops[i]
+                if op.kind == "OJM":
+                    spk = sorted_parent_keys[op.pjtt_key]
+                    ck_all = value_cache.get(
+                        (op.source_key, (op.join_child_column,))
+                    )
+                    if ck_all is not None:
+                        # factored child key: span stats with no re-read
+                        cnt = np.searchsorted(spk, ck_all, side="right") \
+                            - np.searchsorted(spk, ck_all, side="left")
+                        tot = int(cnt.sum()) if len(cnt) else 0
+                        mx = int(cnt.max()) if len(cnt) else 0
+                        row_counts[op.source_key] = len(ck_all)
                     else:
-                        n = row_counts.get(op.source_key)
-                        if n is None:
-                            n = dataset_for(op.source_key).count()
-                            row_counts[op.source_key] = n
-                        op_spans[i] = (n, 1)
-                        total += n
-                pred_candidates[pred] = total
+                        tot = mx = n = 0
+                        ds = (
+                            dataset_for(op.source_key)
+                            .project(
+                                op.join_child_column,
+                                fill=fill_of(op.source_key),
+                                pushdown=mplan is not None,
+                            )
+                            .encode(dct)
+                        )
+                        for block in _spanned("ingest.encode", ds.iter_blocks(prefetch)):
+                            ck = block.columns[op.join_child_column]
+                            cnt = np.searchsorted(spk, ck, side="right") \
+                                - np.searchsorted(spk, ck, side="left")
+                            if len(cnt):
+                                tot += int(cnt.sum())
+                                mx = max(mx, int(cnt.max()))
+                            n += block.n_rows
+                        row_counts[op.source_key] = n
+                    op_spans[i] = (tot, mx)
+                    total += tot
+                else:
+                    n = count_rows(op.source_key)
+                    op_spans[i] = (n, 1)
+                    total += n
+            pred_candidates[pred] = total
 
         # ---- run the ops, block-at-a-time
         triples_out: dict[str, dict[str, list[np.ndarray]]] = {}
@@ -914,7 +953,10 @@ class Engine:
                         blocks = (
                             op_blocks(op)
                             if mplan is not None
-                            else op_dataset(op).iter_blocks(prefetch)
+                            # the unplanned stream encodes as it reads
+                            else _spanned(
+                                "ingest.encode", op_dataset(op).iter_blocks(prefetch)
+                            )
                         )
                         for block in blocks:
                             for batch in pipeline.batches(
@@ -934,6 +976,7 @@ class Engine:
                     if not overflow:
                         triples_out[pred] = out
                         break
+                    reg.inc("ingest.replays")
                     cap *= 2
 
         if mplan is None:
@@ -959,19 +1002,10 @@ class Engine:
                     indexes.pop(pk, None)
                     sorted_parent_keys.pop(pk, None)
 
-        # emit in the op plan's predicate order regardless of group
-        # scheduling: the written KG is byte-identical planner-on/off
-        final = {
-            pred: {
-                k: np.concatenate(v) if v else np.zeros(0, np.int32)
-                for k, v in triples_out[pred].items()
-            }
-            for pred in exec_plan.by_predicate
-        }
         stats = {pred: stats[pred] for pred in exec_plan.by_predicate}
         return KGResult(
             dictionary=dct,
-            triples=final,
+            triples=_assemble(exec_plan, triples_out),
             stats=stats,
             wall_time_s=time.perf_counter() - t0,
             engine="stream",

@@ -73,8 +73,9 @@ def main(argv: list[str] | None = None) -> None:
                          "with repro.api.connect)")
     ap.add_argument("--trace", default=None, metavar="OUT.json",
                     help="record a Chrome trace-event JSON of the run "
-                         "(per-block read/project/encode spans with "
-                         "--stream; open in Perfetto / chrome://tracing)")
+                         "(the job's phases, the engine's ingest.* spans, "
+                         "per-block stream stages with --stream; open in "
+                         "Perfetto / chrome://tracing)")
     args = ap.parse_args(argv)
 
     from repro.jaxcache import enable_compile_cache

@@ -18,6 +18,13 @@ on by the ``--trace out.json`` CLI flags (``rdfize`` / ``query`` /
 
     add_complete("queue_wait", "serve", t_enq_ns, t_start_ns, req=7)
         ...                       # retroactive span from raw timestamps
+
+While tracing is on, a :meth:`Tracer.span` block (not a retroactive
+:meth:`Tracer.add_complete` span) also runs inside a
+``jax.profiler.TraceAnnotation`` named ``repro.<name>`` with the span's
+``args``, provided JAX is already imported: a ``jax.profiler`` capture then
+holds the span on its host plane, on the profiler's clock beside the
+device events.  This module never imports JAX itself.
 """
 
 from __future__ import annotations
@@ -26,10 +33,12 @@ import collections
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
 
 _DEFAULT_CAPACITY = 1 << 16
+_OFF = contextlib.nullcontext()
 
 
 class Tracer:
@@ -87,16 +96,25 @@ class Tracer:
                 self.dropped += 1
             self._events.append(ev)
 
-    @contextlib.contextmanager
     def span(self, name: str, cat: str = "", **args):
         """Time a block; records on exit (exceptions included — the span
         still lands, so a failing dispatch is visible in the trace)."""
         if not self.enabled:
-            yield
-            return
+            return _OFF
+        return self._span(name, cat, args)
+
+    @contextlib.contextmanager
+    def _span(self, name: str, cat: str, args: dict):
+        jax = sys.modules.get("jax")
+        profiled = (
+            jax.profiler.TraceAnnotation(f"repro.{name}", **args)
+            if jax is not None
+            else _OFF
+        )
         t0 = time.perf_counter_ns()
         try:
-            yield
+            with profiled:
+                yield
         finally:
             self.add_complete(name, cat, t0, time.perf_counter_ns(), **args)
 

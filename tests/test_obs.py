@@ -241,3 +241,63 @@ def test_tracer_span_records_on_exception():
 
 def test_global_tracer_is_a_singleton():
     assert get_tracer() is get_tracer()
+
+
+# ------------------------------------------------- the profiler's clock
+
+
+def test_span_lands_on_the_profiler_host_plane(tmp_path):
+    """With tracing on, a ``span()`` inside a ``jax.profiler`` capture is
+    on the capture's host plane as ``repro.<name>``; the Chrome export
+    keeps the bare name."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    tr = Tracer()
+    tr.enable()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with tr.span("probe", cat="test", rows=3, flag=True):
+            jnp.arange(8).sum().block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = [e.name for p in ProfileData.from_file(path).planes if p.name.startswith("/host:")
+            for ln in p.lines for e in ln.events]
+    assert "repro.probe" in host
+    assert [e["name"] for e in tr.export()["traceEvents"]] == ["probe"]
+
+
+def test_annotations_only_while_tracing(monkeypatch):
+    """Tracing off builds no ``TraceAnnotation``; on, each ``span()``
+    builds one and a retroactive span none."""
+    import jax
+
+    built = []
+
+    class Counting:
+        def __init__(self, name, **kw):
+            built.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    tr = Tracer()
+    with tr.span("off", cat="t", rows=1):
+        pass
+    assert built == []
+    tr.enable()
+    with tr.span("on", cat="t", rows=1):
+        pass
+    tr.add_complete("late", "t", 0, 10)
+    assert built == ["repro.on"]
+    assert [e["name"] for e in tr.export()["traceEvents"]] == ["on", "late"]
