@@ -22,8 +22,10 @@ dictionary ids), ``ingest.pjtt_build``, ``ingest.size`` (the sizing
 pre-pass), ``ingest.step`` per batch with its children ``ingest.sync``
 (every blocking read of the step's outputs) and ``ingest.compact`` (the
 host-side selection of the emitted rows), and ``ingest.assemble``; the
-counters ``ingest.host_syncs`` (blocking device-to-host reads) and
-``ingest.replays`` (predicates replayed after a PTT overflow).
+counters ``ingest.host_syncs`` (blocking device-to-host reads),
+``ingest.replays`` (predicates replayed after a PTT overflow), and
+``ingest.join_lanes`` and ``ingest.join_candidates`` (an OJM step's padded
+``rows × K`` probe lanes, and the valid ones among them).
 """
 
 from __future__ import annotations
@@ -123,8 +125,8 @@ def _build_sorted(keys, subjects):
 
 
 @jax.jit
-def _build_hash(keys, subjects):
-    return pjtt.build_hash(keys, subjects)
+def _hash_index(srt):
+    return pjtt.hash_index(srt)
 
 
 @jax.jit
@@ -281,8 +283,17 @@ def _touch_counters() -> None:
     """Create the engine's counters, so that a run without a sync or a
     replay reads 0 rather than nothing."""
     reg = obs.get_registry()
-    reg.counter("ingest.host_syncs")
-    reg.counter("ingest.replays")
+    for name in ("ingest.host_syncs", "ingest.replays", "ingest.join_lanes",
+                 "ingest.join_candidates"):
+        reg.counter(name)
+
+
+def _build_index(kd, sd, join_strategy: str):
+    """The PJTT of one parent and the sorted keys its spans count: each
+    distinct ``(key, subject)`` pair once, so a probe's width is the
+    largest number of distinct subjects of one key."""
+    srt = _build_sorted(kd, sd)
+    return (_hash_index(srt) if join_strategy == "hash" else srt), srt.skeys
 
 
 # --------------------------------------------------------------------------
@@ -376,8 +387,10 @@ class Engine:
                     value_cache[key] = self._term_values(dct, table, columns)
             return value_cache[key]
 
-        # ---- build PJTTs once per (parent map, join column)
+        # ---- build PJTTs once per (parent map, join column), each with
+        # the sorted keys whose spans size its joins
         indexes: dict[str, tuple] = {}
+        span_keys: dict[str, jnp.ndarray] = {}
         for pkey, (psrc, pcol, _ppat, pcols) in exec_plan.pjtt_builds.items():
             pkeys = values_for(psrc, (pcol,))
             psubj = values_for(psrc, pcols)
@@ -385,11 +398,14 @@ class Engine:
                 kd = jnp.asarray(pkeys)
                 sd = jnp.asarray(psubj)
                 if cfg.engine == "naive":
-                    indexes[pkey] = (kd, sd)  # raw arrays for the nested loop
-                elif cfg.join_strategy == "hash":
-                    indexes[pkey] = _build_hash(kd, sd)
+                    # raw arrays for the nested loop, which emits every raw
+                    # match: raw spans size it
+                    indexes[pkey] = (kd, sd)
+                    span_keys[pkey] = jnp.sort(kd)
                 else:
-                    indexes[pkey] = _build_sorted(kd, sd)
+                    indexes[pkey], span_keys[pkey] = _build_index(
+                        kd, sd, cfg.join_strategy
+                    )
 
         # ---- per-predicate candidate estimate -> PTT capacity
         stats: dict[str, PredicateStats] = {}
@@ -402,13 +418,13 @@ class Engine:
                 op = exec_plan.ops[i]
                 n_child = len(values_for(op.source_key, op.subj_columns))
                 if op.kind == "OJM":
-                    # exact |N_p| and max span from the sorted parent keys;
+                    # exact |N_p| and max span from the index's sorted keys;
                     # sizes the PTT and the padded-ragged probe width
-                    pk = values_for(op.parent_source_key, (op.parent_join_column,))
                     ck = values_for(op.source_key, (op.join_child_column,))
                     with obs.span("ingest.size", op=i):
-                        skeys = jnp.sort(jnp.asarray(pk))
-                        tot, mx = _span_stats(skeys, jnp.asarray(ck))
+                        tot, mx = _span_stats(
+                            span_keys[op.pjtt_key], jnp.asarray(ck)
+                        )
                         tot, mx = int(tot), int(mx)
                         obs.get_registry().inc("ingest.host_syncs", 2)
                     op_spans[i] = (tot, mx)
@@ -486,9 +502,10 @@ class Engine:
                 hi, lo, spat, sv, pid, opat, ov, valid
             )
             outputs = (is_new, ovf)
+        reg = obs.get_registry()
         with obs.span("ingest.sync"):
             got = [np.asarray(x) for x in outputs]  # one blocking read each
-            obs.get_registry().inc("ingest.host_syncs", len(got))
+            reg.inc("ingest.host_syncs", len(got))
         with obs.span("ingest.compact"):
             if op.kind == "OJM":
                 trunc, is_new, v, psubj, ovf = got
@@ -497,7 +514,10 @@ class Engine:
                         f"PJTT span exceeded max_matches={K}; "
                         "re-run with a larger max_matches"
                     )
-                st.n_candidates += int(v.sum())
+                n_cand = int(v.sum())
+                st.n_candidates += n_cand
+                reg.inc("ingest.join_lanes", v.size)
+                reg.inc("ingest.join_candidates", n_cand)
                 rows, ks = np.nonzero(is_new & v)
                 obj = psubj[rows, ks]
             else:
@@ -854,13 +874,12 @@ class Engine:
                 with obs.span("ingest.encode", source=psrc):
                     pkeys, psubj = parent_columns(psrc, pcol, pcols)
                 with obs.span("ingest.pjtt_build", pjtt=pkey):
-                    kd, sd = jnp.asarray(pkeys), jnp.asarray(psubj)
-                    if cfg.join_strategy == "hash":
-                        indexes[pkey] = _build_hash(kd, sd)
-                    else:
-                        indexes[pkey] = _build_sorted(kd, sd)
+                    indexes[pkey], skeys = _build_index(
+                        jnp.asarray(pkeys), jnp.asarray(psubj), cfg.join_strategy
+                    )
                 with obs.span("ingest.size", pjtt=pkey):
-                    sorted_parent_keys[pkey] = np.sort(pkeys)
+                    sorted_parent_keys[pkey] = np.asarray(skeys)
+                    reg.inc("ingest.host_syncs")
                 parent_counts[pkey] = len(pkeys)
                 row_counts[psrc] = len(pkeys)
 

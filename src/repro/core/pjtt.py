@@ -16,8 +16,11 @@ Two interchangeable physical strategies (DESIGN.md §6):
 
 Both return probes in a *padded-ragged* layout: ``(m, max_matches)`` subject
 ids plus a validity mask — the TPU-native encoding of the N-M join output.
-Duplicate parent ``(key, subject)`` pairs are kept in the span but masked with
-a ``-1`` subject so the PJTT behaves as the paper's set semantics.
+A span holds each distinct ``(key, subject)`` pair once, as the paper's PJTT
+maps a value to a *set* of subjects: repeated pairs are masked with a ``-1``
+subject and moved out of their key's span to a tail under the key
+``INT32_MAX``, which is never a dictionary id.  So a key's span count is its
+number of distinct subjects, and that is the probe width it needs.
 """
 
 from __future__ import annotations
@@ -38,14 +41,14 @@ _I32_MAX = jnp.iinfo(jnp.int32).max
 
 
 class PJTTSorted(NamedTuple):
-    skeys: jnp.ndarray  # int32[n]  parent join-key values, sorted
+    skeys: jnp.ndarray  # int32[n]  parent join-key values, sorted; dups INT32_MAX
     ssubj: jnp.ndarray  # int32[n]  parent subject values, co-sorted; -1 = dup
 
 
 class PJTTHash(NamedTuple):
     tkey: jnp.ndarray    # int32[cap]  join-key or -1 (empty)
     tstart: jnp.ndarray  # int32[cap]  span start into ssubj
-    tcount: jnp.ndarray  # int32[cap]  span length
+    tcount: jnp.ndarray  # int32[cap]  span length: distinct subjects
     ssubj: jnp.ndarray   # int32[n]    sorted subjects; -1 = dup
 
 
@@ -74,11 +77,31 @@ def _mask_dups(skeys: jnp.ndarray, ssubj: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(prev_same, _SUBJ_MASKED, ssubj)
 
 
+def _compact(skeys: jnp.ndarray, ssubj: jnp.ndarray) -> PJTTSorted:
+    """Move each masked pair out of its key's span: a stable partition of
+    the sorted pairs, kept ones first, the masked ones after them under the
+    key ``INT32_MAX`` — what a stable re-sort by key gives, without a sort.
+    The kept pairs stay in (key, subject) order and the length stays n."""
+    keep = ssubj != _SUBJ_MASKED
+    n_keep = jnp.sum(keep, dtype=jnp.int32)
+    dest = jnp.where(
+        keep,
+        jnp.cumsum(keep, dtype=jnp.int32) - 1,
+        n_keep + jnp.cumsum(~keep, dtype=jnp.int32) - 1,
+    )
+    return PJTTSorted(
+        skeys=jnp.zeros_like(skeys).at[dest].set(
+            jnp.where(keep, skeys, _I32_MAX), unique_indices=True
+        ),
+        ssubj=jnp.zeros_like(ssubj).at[dest].set(ssubj, unique_indices=True),
+    )
+
+
 def build_sorted(keys: jnp.ndarray, subjects: jnp.ndarray) -> PJTTSorted:
     """Build the sorted-strategy PJTT from parent rows.  Cost: one sort —
-    the paper's |N_parent| build term."""
+    the paper's |N_parent| build term — and one linear compaction."""
     skeys, ssubj = _lexsort_pairs(keys, subjects)
-    return PJTTSorted(skeys=skeys, ssubj=_mask_dups(skeys, ssubj))
+    return _compact(skeys, _mask_dups(skeys, ssubj))
 
 
 def probe_sorted(
@@ -92,10 +115,14 @@ def probe_sorted(
 def build_hash(keys: jnp.ndarray, subjects: jnp.ndarray) -> PJTTHash:
     """Build the hash-strategy PJTT: group via sort, then insert each unique
     key with its (start, count) span into an open-addressing map."""
-    n = keys.shape[0]
-    skeys, ssubj0 = _lexsort_pairs(keys, subjects)
-    ssubj = _mask_dups(skeys, ssubj0)
+    return hash_index(build_sorted(keys, subjects))
 
+
+def hash_index(srt: PJTTSorted) -> PJTTHash:
+    """The hash-strategy PJTT over a built sorted one: each key's span of
+    distinct subjects, as ``(start, count)`` in an open-addressing map."""
+    skeys, ssubj = srt
+    n = skeys.shape[0]
     is_start = jnp.concatenate([jnp.array([True]), skeys[1:] != skeys[:-1]])
     seg_id = jnp.cumsum(is_start) - 1  # group index per sorted row
     counts_per_seg = jax.ops.segment_sum(

@@ -1,6 +1,8 @@
 """Core engine: hash set, PJTT strategies, operators, and the paper's
 operation-count (φ) model."""
 
+import collections
+
 import numpy as np
 import pytest
 import jax.numpy as jnp
@@ -119,6 +121,92 @@ def test_pjtt_truncation_flag():
     idx = pjtt.build_sorted(pk, ps)
     pr = pjtt.probe_sorted(idx, jnp.zeros(1, jnp.int32), 4)
     assert bool(pr.truncated)
+
+
+def _probe(strategy, pk, ps, ck, K):
+    pk, ps, ck = (jnp.asarray(np.asarray(a, np.int32)) for a in (pk, ps, ck))
+    if strategy == "sorted":
+        return pjtt.probe_sorted(pjtt.build_sorted(pk, ps), ck, K)
+    return pjtt.probe_hash(pjtt.build_hash(pk, ps), ck, K)
+
+
+def _distinct_subjects(pk, ps):
+    d = collections.defaultdict(set)
+    for k, s in zip(pk, ps):
+        d[k].add(s)
+    return d
+
+
+@pytest.mark.parametrize("strategy", ["sorted", "hash"])
+@pytest.mark.parametrize("n,keys,subjects", [(200, 5, 4), (1000, 30, 3), (333, 1, 7)])
+def test_pjtt_span_holds_distinct_pairs(strategy, n, keys, subjects):
+    """Parent rows that repeat (key, subject) pairs: a probe's span holds
+    each key's distinct subjects once, ascending and packed at the front,
+    so K = the largest distinct count probes without truncation."""
+    rng = np.random.default_rng(n + keys)
+    pk = rng.integers(0, keys, n).astype(np.int32)
+    ps = rng.integers(0, subjects, n).astype(np.int32)
+    ck = np.arange(keys + 2, dtype=np.int32)
+    d = _distinct_subjects(pk, ps)
+    K = max(len(v) for v in d.values())
+    assert K < np.bincount(pk).max()  # the raw spans are wider
+    pr = _probe(strategy, pk, ps, ck, K)
+    assert not bool(pr.truncated)
+    subs, valid = np.asarray(pr.subjects), np.asarray(pr.valid)
+    for i, k in enumerate(ck.tolist()):
+        want = sorted(d.get(k, ()))
+        assert valid[i].sum() == len(want)
+        assert valid[i][: len(want)].all()
+        assert subs[i][: len(want)].tolist() == want
+    assert bool(_probe(strategy, pk, ps, ck, K - 1).truncated)
+
+
+@pytest.mark.parametrize("strategy", ["sorted", "hash"])
+def test_pjtt_raw_span_beyond_K_distinct_within(strategy):
+    """Key 1 has six parent rows but three distinct subjects: K = 3 holds
+    it, and the key after it starts right behind its distinct span."""
+    pk = [1, 1, 1, 1, 1, 1, 2, 2, 2]
+    ps = [7, 9, 7, 8, 9, 7, 5, 5, 5]
+    pr = _probe(strategy, pk, ps, [1, 2, 3], 3)
+    assert not bool(pr.truncated)
+    subs, valid = np.asarray(pr.subjects), np.asarray(pr.valid)
+    assert subs[0].tolist() == [7, 8, 9] and valid[0].all()
+    assert subs[1][0] == 5 and valid[1].tolist() == [True, False, False]
+    assert not valid[2].any()
+    assert bool(_probe(strategy, pk, ps, [1, 2, 3], 2).truncated)
+
+
+def test_engines_agree_when_parents_repeat_pairs():
+    """Parent rows repeat, so the raw widest span is wider than the
+    distinct one.  With max_matches left to the engine, the eager sorted
+    and hash engines (sized by distinct spans), the streamed engine and
+    the naive engine (sized by raw spans) give the same KG and none
+    reports a truncated span."""
+    from repro.core.executor import create_kg
+    from repro.rml import generator
+    from repro.rml.plan import build_plan
+
+    tb = generator.make_testbed("OJM", 800, 0.75, n_poms=2, seed=3)
+    tables = {"csv:child.csv": tb.child, "csv:parent.csv": tb.parent}
+    plan = build_plan(tb.doc).exec_plan
+    for op in (op for op in plan.ops if op.kind == "OJM"):
+        psrc, pcol, _ppat, pcols = plan.pjtt_builds[op.pjtt_key]
+        keys = tables[psrc][pcol]
+        d = _distinct_subjects(keys, zip(*(tables[psrc][c] for c in pcols)))
+        raw = collections.Counter(keys)
+        ck = set(tables[op.source_key][op.join_child_column])
+        assert max(raw[k] for k in ck) > max(len(d[k]) for k in ck)
+    runs = {
+        "sorted": dict(join_strategy="sorted"),
+        "hash": dict(join_strategy="hash"),
+        "stream": dict(stream=True, block_rows=300),
+        "naive": dict(engine="naive"),
+    }
+    kgs = {k: create_kg(tb.doc, tables=tables, **cfg) for k, cfg in runs.items()}
+    assert kgs["sorted"].as_set() == kgs["hash"].as_set() == kgs["naive"].as_set()
+    want = kgs["sorted"].sorted_ntriples()
+    assert len(want) > 0
+    assert all(kg.sorted_ntriples() == want for kg in kgs.values())
 
 
 # ---------------------------------------------------------------- operators

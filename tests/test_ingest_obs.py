@@ -1,6 +1,7 @@
-"""The ingest engine's own instrumentation: the ``ingest.host_syncs`` and
-``ingest.replays`` counters against counts worked out from the batches,
-and the ``ingest.*`` spans nested properly on the engine's thread."""
+"""The ingest engine's own instrumentation: the ``ingest.host_syncs``,
+``ingest.replays``, ``ingest.join_lanes`` and ``ingest.join_candidates``
+counters against counts worked out from the batches and the tables, and
+the ``ingest.*`` spans nested properly on the engine's thread."""
 
 import collections
 
@@ -16,6 +17,8 @@ from repro.rml.plan import build_plan
 # psubj and ovf; a dedup step reads is_new and ovf
 READS_PER_BATCH = {"OJM": 5}
 SIZING_READS = 2  # the eager engine's int() of |N_p| and the max span
+COUNTERS = ("ingest.host_syncs", "ingest.replays", "ingest.join_lanes",
+            "ingest.join_candidates")
 
 
 def _tables(tb):
@@ -25,19 +28,46 @@ def _tables(tb):
     return tables
 
 
+def _batches(n, batch_size, stream, block_rows):
+    """The number of device batches of an ``n``-row op, and the rows each
+    is padded to."""
+    if stream:
+        rows = min(batch_size, block_rows)
+        blocks = [min(block_rows, n - s) for s in range(0, n, block_rows)]
+        return sum(-(-b // rows) for b in blocks), rows
+    bs = min(batch_size, pipeline.pick_batch_size(n))
+    return -(-n // bs), bs
+
+
 def expected_host_syncs(doc, tables, batch_size, stream=False, block_rows=None):
-    total = 0
-    for op in build_plan(doc).exec_plan.ops:
+    plan = build_plan(doc).exec_plan
+    # the streamed engine reads each PJTT's sorted keys back for its sizing
+    total = len(plan.pjtt_builds) if stream else 0
+    for op in plan.ops:
         n = len(next(iter(tables[op.source_key].values())))
-        per = READS_PER_BATCH.get(op.kind, 2)
-        if stream:
-            rows = min(batch_size, block_rows)
-            blocks = [min(block_rows, n - s) for s in range(0, n, block_rows)]
-            total += per * sum(-(-b // rows) for b in blocks)
-        else:
-            bs = min(batch_size, pipeline.pick_batch_size(n))
-            total += per * -(-n // bs) + (SIZING_READS if op.kind == "OJM" else 0)
+        n_batches, _rows = _batches(n, batch_size, stream, block_rows)
+        total += READS_PER_BATCH.get(op.kind, 2) * n_batches
+        if op.kind == "OJM" and not stream:
+            total += SIZING_READS
     return total
+
+
+def expected_join_lanes(doc, tables, batch_size, stream=False, block_rows=None):
+    """The OJM steps' probe lanes and the valid ones among them: each op's
+    padded rows × K, K the most distinct parent subjects of a child key,
+    and the child rows' distinct-subject counts."""
+    plan = build_plan(doc).exec_plan
+    lanes = candidates = 0
+    for op in (op for op in plan.ops if op.kind == "OJM"):
+        psrc, pcol, _ppat, pcols = plan.pjtt_builds[op.pjtt_key]
+        subjects = collections.defaultdict(set)
+        for key, *subj in zip(tables[psrc][pcol], *(tables[psrc][c] for c in pcols)):
+            subjects[key].add(tuple(subj))
+        counts = [len(subjects[k]) for k in tables[op.source_key][op.join_child_column]]
+        n_batches, rows = _batches(len(counts), batch_size, stream, block_rows)
+        lanes += n_batches * rows * max(max(counts), 1)
+        candidates += sum(counts)
+    return lanes, candidates
 
 
 def _counted(doc, tables, **config):
@@ -45,7 +75,7 @@ def _counted(doc, tables, **config):
     before = reg.snapshot()["counters"]
     result = create_kg(doc, tables=tables, **config)
     after = reg.snapshot()["counters"]
-    delta = {k: after[k] - before.get(k, 0) for k in ("ingest.host_syncs", "ingest.replays")}
+    delta = {k: after[k] - before.get(k, 0) for k in COUNTERS}
     return result, delta
 
 
@@ -67,6 +97,24 @@ def test_host_syncs_count_the_batches(kind, engine):
                                block_rows=config.get("block_rows"))
     assert delta["ingest.host_syncs"] == want
     assert delta["ingest.replays"] == 0
+
+
+@pytest.mark.parametrize("engine", sorted(ENGINES))
+@pytest.mark.parametrize("kind", ["OJM", "SOM"])
+def test_join_lanes_count_the_distinct_spans(kind, engine):
+    """An OJM step probes rows × K lanes, K the widest distinct span: the
+    parent's repeated rows widen no span.  A SOM job joins nothing."""
+    tb = generator.make_testbed(kind, 1500, 0.75, n_poms=2, seed=5)
+    tables = _tables(tb)
+    config = ENGINES[engine]
+    result, delta = _counted(tb.doc, tables, **config)
+    lanes, candidates = expected_join_lanes(
+        tb.doc, tables, config["batch_size"], stream=config.get("stream", False),
+        block_rows=config.get("block_rows"))
+    assert (delta["ingest.join_lanes"], delta["ingest.join_candidates"]) == (lanes, candidates)
+    joined = sum(st.n_candidates for st in result.stats.values() if st.kind == "OJM")
+    assert delta["ingest.join_candidates"] == joined
+    assert (lanes > 0) == (kind == "OJM")
 
 
 @pytest.mark.parametrize("engine", sorted(ENGINES))
