@@ -25,7 +25,10 @@ host-side selection of the emitted rows), and ``ingest.assemble``; the
 counters ``ingest.host_syncs`` (blocking device-to-host reads),
 ``ingest.replays`` (predicates replayed after a PTT overflow), and
 ``ingest.join_lanes`` and ``ingest.join_candidates`` (an OJM step's padded
-``rows × K`` probe lanes, and the valid ones among them).
+``rows × K`` probe lanes, and the valid ones among them), and
+``ingest.null_terms`` (source rows an op masks because a term it reads —
+subject, object or child join key — is NULL; counted once a predicate
+completes, so a replay does not count twice).
 """
 
 from __future__ import annotations
@@ -43,7 +46,7 @@ from repro.core import hashing, naive, pjtt, planner
 from repro.core import hashset
 from repro.core.hashset import next_pow2
 from repro.data import pipeline
-from repro.data.encoder import Dictionary, join_columns
+from repro.data.encoder import NULL_ID, Dictionary, join_columns
 from repro.data.sources import SourceCache
 from repro.data.terms import render_term
 from repro.rml.model import MappingDocument
@@ -151,6 +154,7 @@ class PredicateStats:
     n_unique: int = 0       # |S_p|
     n_parent: int = 0
     n_child: int = 0
+    n_null: int = 0         # lanes masked: a term they read is NULL
 
     def phi_optimized(self) -> float:
         base = self.n_candidates + 2 * self.n_unique
@@ -284,7 +288,7 @@ def _touch_counters() -> None:
     replay reads 0 rather than nothing."""
     reg = obs.get_registry()
     for name in ("ingest.host_syncs", "ingest.replays", "ingest.join_lanes",
-                 "ingest.join_candidates"):
+                 "ingest.join_candidates", "ingest.null_terms"):
         reg.counter(name)
 
 
@@ -399,7 +403,8 @@ class Engine:
                 sd = jnp.asarray(psubj)
                 if cfg.engine == "naive":
                     # raw arrays for the nested loop, which emits every raw
-                    # match: raw spans size it
+                    # match: raw spans size it; a NULL key matches nothing
+                    kd = jnp.where(kd == NULL_ID, jnp.iinfo(jnp.int32).max, kd)
                     indexes[pkey] = (kd, sd)
                     span_keys[pkey] = jnp.sort(kd)
                 else:
@@ -481,7 +486,11 @@ class Engine:
             return self._step(op, spat, pid, opat, hi, lo, batch, index, K, out, st)
 
     def _step(self, op, spat, pid, opat, hi, lo, batch, index, K, out, st):
-        valid = jnp.asarray(batch.valid)
+        # a lane whose subject, object or child key is NULL is no candidate
+        other = batch.arrays["jkey" if op.kind == "OJM" else "obj"]
+        live = batch.valid & (batch.arrays["subj"] != NULL_ID) & (other != NULL_ID)
+        st.n_null += int(batch.valid.sum() - live.sum())
+        valid = jnp.asarray(live)
         sv = jnp.asarray(batch.arrays["subj"])
         if op.kind == "OJM":
             ck = jnp.asarray(batch.arrays["jkey"])
@@ -522,8 +531,8 @@ class Engine:
                 obj = psubj[rows, ks]
             else:
                 is_new, ovf = got
-                st.n_candidates += int(batch.valid.sum())
-                rows = np.nonzero(is_new & batch.valid)[0]
+                st.n_candidates += int(live.sum())
+                rows = np.nonzero(is_new & live)[0]
                 obj = batch.arrays["obj"][rows]
             n_emit = len(rows)
             out["subj_val"].append(batch.arrays["subj"][rows].astype(np.int32))
@@ -549,7 +558,7 @@ class Engine:
                 hi, lo = table.hi, table.lo
                 out = {k: [] for k in ("subj_pat", "subj_val", "obj_pat", "obj_val")}
                 st = stats[pred]
-                st.n_candidates = st.n_unique = st.n_parent = st.n_child = 0
+                st.n_candidates = st.n_unique = st.n_parent = st.n_child = st.n_null = 0
                 overflow = False
                 for i in op_idxs:
                     op = exec_plan.ops[i]
@@ -589,6 +598,7 @@ class Engine:
                         break
                 if not overflow:
                     triples_out[pred] = out
+                    obs.get_registry().inc("ingest.null_terms", st.n_null)
                     break
                 obs.get_registry().inc("ingest.replays")
                 cap *= 2  # replay this predicate with a bigger table
@@ -954,7 +964,7 @@ class Engine:
                         for k in ("subj_pat", "subj_val", "obj_pat", "obj_val")
                     }
                     st = stats[pred]
-                    st.n_candidates = st.n_unique = st.n_parent = st.n_child = 0
+                    st.n_candidates = st.n_unique = st.n_parent = st.n_child = st.n_null = 0
                     overflow = False
                     for i in op_idxs:
                         op = exec_plan.ops[i]
@@ -994,6 +1004,7 @@ class Engine:
                             break
                     if not overflow:
                         triples_out[pred] = out
+                        reg.inc("ingest.null_terms", st.n_null)
                         break
                     reg.inc("ingest.replays")
                     cap *= 2
@@ -1058,10 +1069,10 @@ class Engine:
                     jr = _naive_join_step(pkeys, psubj, K, ck)
                     if bool(jr.truncated):
                         raise RuntimeError("naive join exceeded max_matches")
-                    m = n
-                    subj = np.broadcast_to(subj_vals[:, None], (m, K)).reshape(-1)
+                    live = (subj_vals != NULL_ID) & (np.asarray(ck) != NULL_ID)
+                    subj = np.broadcast_to(subj_vals[:, None], (n, K)).reshape(-1)
                     obj = np.asarray(jr.subjects).reshape(-1)
-                    v = np.asarray(jr.valid).reshape(-1)
+                    v = (np.asarray(jr.valid) & live[:, None]).reshape(-1)
                     khi, klo = _naive_keys_step(
                         spat, jnp.asarray(subj), pid, opat, jnp.asarray(obj)
                     )
@@ -1072,10 +1083,12 @@ class Engine:
                         obj = np.zeros_like(subj_vals)
                     else:
                         obj = values_for(op.source_key, op.obj_columns)
-                    subj, v = subj_vals, np.ones(n, bool)
+                    live = (subj_vals != NULL_ID) & (obj != NULL_ID)
+                    subj, v = subj_vals, live
                     khi, klo = _naive_keys_step(
                         spat, jnp.asarray(subj), pid, opat, jnp.asarray(obj)
                     )
+                st.n_null += int(n - live.sum())
                 khis.append(np.asarray(khi))
                 klos.append(np.asarray(klo))
                 valids.append(v)
@@ -1087,6 +1100,7 @@ class Engine:
             klo = np.concatenate(klos)
             v = np.concatenate(valids)
             st.n_candidates = int(v.sum())
+            obs.get_registry().inc("ingest.null_terms", st.n_null)
             dd = _naive_dedup(jnp.asarray(khi), jnp.asarray(klo), jnp.asarray(v))
             mask = np.asarray(dd.uniq_mask)
             st.n_unique = int(mask.sum())

@@ -33,10 +33,11 @@ import numpy as np
 
 from repro.core import hashing
 from repro.core.hashset import next_pow2
+from repro.data.encoder import NULL_ID
 
 MAX_PROBE_ROUNDS = 64
 _KEY_EMPTY = np.int32(-1)  # join keys are dictionary ids >= 0
-_SUBJ_MASKED = np.int32(-1)
+_SUBJ_MASKED = np.int32(NULL_ID)  # a repeated pair, or a NULL subject
 _I32_MAX = jnp.iinfo(jnp.int32).max
 
 
@@ -100,6 +101,9 @@ def _compact(skeys: jnp.ndarray, ssubj: jnp.ndarray) -> PJTTSorted:
 def build_sorted(keys: jnp.ndarray, subjects: jnp.ndarray) -> PJTTSorted:
     """Build the sorted-strategy PJTT from parent rows.  Cost: one sort —
     the paper's |N_parent| build term — and one linear compaction."""
+    # a pair with a NULL key or subject is masked like a repeated pair,
+    # so it lands in the INT32_MAX tail and never matches
+    subjects = jnp.where(keys == NULL_ID, _SUBJ_MASKED, subjects)
     skeys, ssubj = _lexsort_pairs(keys, subjects)
     return _compact(skeys, _mask_dups(skeys, ssubj))
 

@@ -9,8 +9,10 @@ Responsibilities (paper's "RML Triples Map Syntax Interpreter"):
 * group ops by predicate so PTT capacities can be sized from the total
   candidate count per predicate.
 
-Term patterns are namespaced strings (``iri:`` templates/constants,
-``lit:`` literal references) so output materialization knows the term kind.
+Term patterns are namespaced strings so output materialization knows the
+term kind: ``iri:`` (templates and constants; a template's values are made
+IRI-safe), ``ref:`` (an IRI taken verbatim from a reference), ``lit:`` (a
+plain literal) and ``typ:<datatype> `` (a typed literal).
 """
 
 from __future__ import annotations
@@ -31,12 +33,11 @@ RDF_TYPE = "http://www.w3.org/1999/02/22-rdf-syntax-ns#type"
 
 def term_pattern(term: TermMap) -> str:
     """Canonical namespaced pattern string for a term map."""
-    if term.template is not None:
-        return "iri:" + term.pattern
-    if term.reference is not None:
-        return "lit:{}"
-    c = term.constant or ""
-    return ("iri:" if c.startswith(("http://", "https://", "urn:")) else "lit:") + c
+    if term.is_iri:
+        return ("ref:" if term.reference is not None else "iri:") + term.pattern
+    if term.datatype is not None:
+        return f"typ:{term.datatype} {term.pattern}"
+    return "lit:" + term.pattern
 
 
 @dataclasses.dataclass(frozen=True)

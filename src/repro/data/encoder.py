@@ -5,6 +5,11 @@ int32 id.  Equality of ids == equality of strings across columns and sources,
 which is what makes join keys comparable on device (DESIGN.md §2).  Bulk
 encoding is vectorized with ``np.unique``; only the per-dictionary novel
 values pay a Python-dict insertion.
+
+An empty value is NULL (R2RML: a NULL value gives no term): an empty cell,
+or a multi-column template value with an empty placeholder, encodes to
+:data:`NULL_ID`, which is never a dictionary id, and the engines mask every
+lane that reads it.
 """
 
 from __future__ import annotations
@@ -12,6 +17,12 @@ from __future__ import annotations
 import numpy as np
 
 _SEP = "\x1f"  # joins multi-column template values; cannot occur in CSV cells
+NULL_ID = -1  # the id of a NULL value; dictionary ids are >= 0
+
+
+def is_null(value: str) -> bool:
+    """An empty value, or a joined template value with an empty part."""
+    return value == "" or (_SEP in value and "" in value.split(_SEP))
 
 
 class Dictionary:
@@ -33,11 +44,13 @@ class Dictionary:
         return vid
 
     def encode(self, values: np.ndarray) -> np.ndarray:
-        """Vectorized bulk encode of a 1-D string array -> int32 ids."""
+        """Vectorized bulk encode of a 1-D string array -> int32 ids, with
+        :data:`NULL_ID` for a NULL value (never entered in the table)."""
         values = np.asarray(values)
         uniq, inverse = np.unique(values, return_inverse=True)
         uniq_ids = np.fromiter(
-            (self.encode_scalar(str(u)) for u in uniq), dtype=np.int32, count=len(uniq)
+            (NULL_ID if is_null(v) else self.encode_scalar(v) for v in map(str, uniq)),
+            dtype=np.int32, count=len(uniq),
         )
         return uniq_ids[inverse].astype(np.int32)
 

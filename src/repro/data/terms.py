@@ -16,6 +16,15 @@ import re
 
 from repro.data.encoder import Dictionary, render_template
 
+# RFC 3987 iunreserved (ALPHA / DIGIT / "-" / "." / "_" / "~" / ucschar), and
+# the multi-column separator, which render_template splits on
+_IUNRESERVED = (
+    "A-Za-z0-9\\-._~\x1f\u00a0-\ud7ff\uf900-\ufdcf\ufdf0-\uffef"
+    + "".join(f"\\U{p:04x}0000-\\U{p:04x}fffd" for p in range(1, 14))
+    + "\\U000e1000-\\U000efffd"
+)
+_IRI_UNSAFE = re.compile(f"[^{_IUNRESERVED}]")
+
 # N-Triples ECHAR escapes; everything else in the forbidden range goes \uXXXX.
 _ESCAPES = {
     "\\": "\\\\",
@@ -56,26 +65,56 @@ def unescape_literal(s: str) -> str:
     return _UNESCAPE.sub(repl, s)
 
 
+def _pct(m: re.Match) -> str:
+    return "".join(f"%{b:02X}" for b in m.group(0).encode("utf-8"))
+
+
+def iri_safe(value: str) -> str:
+    """R2RML §7.3's IRI-safe form: every character outside RFC 3987
+    ``iunreserved`` percent-encoded as UTF-8 octets.  A value that needs no
+    encoding is returned as it is after one regex scan."""
+    if _IRI_UNSAFE.search(value) is None:
+        return value
+    return _IRI_UNSAFE.sub(_pct, value)
+
+
 def render_term(d: Dictionary, pat_id: int, val_id: int) -> str:
-    """(pattern id, value id) -> concrete N-Triples term (``<iri>`` or
-    ``"literal"``).  Patterns are the planner's namespaced strings
-    (``iri:...`` / ``lit:...``); ``{}`` slots take the dictionary value."""
-    pat = d.decode_scalar(pat_id)
-    kind, pattern = pat.split(":", 1)
-    value = d.decode_scalar(val_id) if "{}" in pattern else ""
-    body = render_template(pattern, value) if "{}" in pattern else pattern
-    if kind == "iri":
-        return f"<{body}>"
-    return '"' + escape_literal(body) + '"'
+    """(pattern id, value id) -> concrete N-Triples term (``<iri>``,
+    ``"literal"`` or ``"literal"^^<datatype>``).  Patterns are the
+    planner's namespaced strings; ``{}`` slots take the dictionary value."""
+    kind, pattern = d.decode_scalar(pat_id).split(":", 1)
+    if kind == "typ":
+        datatype, pattern = pattern.split(" ", 1)
+    if "{}" in pattern:
+        value = d.decode_scalar(val_id)
+        pattern = render_template(pattern, iri_safe(value) if kind == "iri" else value)
+    if kind in ("iri", "ref"):
+        return f"<{pattern}>"
+    if kind == "typ":
+        return f'"{escape_literal(pattern)}"^^<{datatype}>'
+    return '"' + escape_literal(pattern) + '"'
+
+
+def split_typed(token: str) -> "tuple[str, str] | None":
+    """``'"body"^^<datatype>'`` -> (raw body, datatype); None for any
+    other token."""
+    at = token.rfind('"^^<')
+    if not token.startswith('"') or at < 1 or not token.endswith(">"):
+        return None
+    return unescape_literal(token[1:at]), token[at + 4:-1]
 
 
 def canonical_term(token: str) -> str:
-    """Normalize a user-supplied constant term (``<iri>`` or a quoted
-    literal, possibly with escapes) to the exact string :func:`render_term`
-    produces, so it can key a rendered-term lookup."""
+    """Normalize a user-supplied constant term (``<iri>``, a quoted
+    literal, possibly with escapes, or a typed literal ``"v"^^<dt>``) to the
+    exact string :func:`render_term` produces, so it can key a
+    rendered-term lookup."""
     token = token.strip()
     if token.startswith("<") and token.endswith(">"):
         return token
+    typed = split_typed(token)
+    if typed is not None:
+        return f'"{escape_literal(typed[0])}"^^<{typed[1]}>'
     if token.startswith('"') and token.endswith('"') and len(token) >= 2:
         return '"' + escape_literal(unescape_literal(token[1:-1])) + '"'
     raise ValueError(f"not an N-Triples term: {token!r}")

@@ -71,12 +71,17 @@ def encode_rendered_term(dictionary: Dictionary, term: str) -> tuple[int, int]:
     scheme :meth:`TripleStore.from_ntriples` uses — shared with the live
     overlay's dictionary append so overlay terms decode/compare exactly
     like base terms."""
-    from repro.data.terms import unescape_literal
+    from repro.data.terms import split_typed, unescape_literal
 
+    typed = split_typed(term)
     if term.startswith("<"):
-        kind, body = "iri", term[1:-1]
+        # a slot of an "iri:" pattern is made IRI-safe, one of "ref:" is not
+        body, fixed, slotted = term[1:-1], "iri:", "ref:"
+    elif typed is not None:
+        body, datatype = typed
+        fixed = slotted = f"typ:{datatype} "
     else:
-        kind, body = "lit", unescape_literal(term[1:-1])
+        body, fixed, slotted = unescape_literal(term[1:-1]), "lit:", "lit:"
     if "{}" in body:
         # a literal '{}' would read as a template slot: route the
         # body through the value side of the (pattern, value) pair
@@ -86,12 +91,12 @@ def encode_rendered_term(dictionary: Dictionary, term: str) -> tuple[int, int]:
                 f"separator; not representable: {term!r}"
             )
         return (
-            dictionary.encode_scalar(f"{kind}:{{}}"),
+            dictionary.encode_scalar(slotted + "{}"),
             dictionary.encode_scalar(body),
         )
     # slotless pattern: render_term never reads the value id —
     # point it at the pattern string to stay in range
-    pid = dictionary.encode_scalar(f"{kind}:{body}")
+    pid = dictionary.encode_scalar(fixed + body)
     return pid, pid
 
 

@@ -43,18 +43,46 @@ def parse_source_key(key: str) -> tuple[str, str, str | None]:
     return fmt, path, iterator or None
 
 
+IRI = "iri"
+LITERAL = "literal"
+
+
 @dataclasses.dataclass(frozen=True)
 class TermMap:
-    """rr:template / rml:reference / rr:constant term map."""
+    """rr:template / rml:reference / rr:constant term map, with its
+    ``rr:termType`` (``"iri"`` or ``"literal"``; None takes the R2RML
+    default) and ``rr:datatype`` (a literal's datatype IRI)."""
 
     template: str | None = None
     reference: str | None = None
     constant: str | None = None
+    term_type: str | None = None
+    datatype: str | None = None
 
     def __post_init__(self):
         n = sum(x is not None for x in (self.template, self.reference, self.constant))
         if n != 1:
             raise ValueError("TermMap needs exactly one of template/reference/constant")
+        if self.term_type not in (None, IRI, LITERAL):
+            raise ValueError(f"unsupported term type {self.term_type!r}")
+        if self.datatype is not None and self.term_type == IRI:
+            raise ValueError("rr:datatype needs a literal term map, not rr:IRI")
+        if self.datatype is not None and re.search(r'[\s<>"{}|^`\\]', self.datatype):
+            raise ValueError(f"datatype is not an IRI: {self.datatype!r}")
+
+    @property
+    def is_iri(self) -> bool:
+        """The term type, R2RML §7.4: a reference or a typed term gives a
+        literal, a template or a constant an IRI, unless ``rr:termType``
+        says otherwise (a constant without it is an IRI when it looks like
+        one)."""
+        if self.term_type is not None:
+            return self.term_type == IRI
+        if self.datatype is not None or self.reference is not None:
+            return False
+        if self.constant is not None:
+            return self.constant.startswith(("http://", "https://", "urn:"))
+        return True
 
     @property
     def kind(self) -> str:
@@ -85,13 +113,14 @@ class TermMap:
         return self.constant  # type: ignore[return-value]
 
     @property
-    def key(self) -> tuple[str, tuple[str, ...]]:
-        """``(pattern, columns)`` — the term map's evaluation identity.
-        Two term maps with the same key over the same logical source
-        produce identical per-row values, which is what lets the mapping
-        planner (:mod:`repro.rml.plan`) factor them into one FunMap-style
-        common subexpression evaluated once per source scan."""
-        return (self.pattern, self.columns)
+    def key(self) -> tuple:
+        """``(pattern, columns, is_iri, datatype)`` — the term map's
+        evaluation identity.  Two term maps with the same key over the same
+        logical source produce identical terms, which is what lets the
+        mapping planner (:mod:`repro.rml.plan`) factor them into one
+        FunMap-style common subexpression evaluated once per source scan;
+        two differently typed terms never share a key."""
+        return (self.pattern, self.columns, self.is_iri, self.datatype)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -119,6 +148,10 @@ class TriplesMap:
     subject: TermMap
     subject_class: str | None = None
     poms: tuple[PredicateObjectMap, ...] = ()
+
+    def __post_init__(self):
+        if not self.subject.is_iri or self.subject.datatype is not None:
+            raise ValueError(f"the subject map of {self.name} must give IRIs")
 
 
 @dataclasses.dataclass(frozen=True)

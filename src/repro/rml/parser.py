@@ -4,10 +4,17 @@ Supports the constructs exercised in the paper's Figure 1 / testbeds:
 ``@prefix``, triples maps with ``rml:logicalSource``, ``rr:subjectMap``
 (template + class), ``rr:predicateObjectMap`` with plain object maps
 (``rr:template`` / ``rml:reference`` / ``rr:constant``), referencing object
-maps (``rr:parentTriplesMap``), and ``rr:joinCondition`` (``rr:child`` /
-``rr:parent``).  Blank-node property lists, ``;``/``,`` lists, IRIs,
+maps (``rr:parentTriplesMap``), one ``rr:joinCondition`` (``rr:child`` /
+``rr:parent``), ``rr:datatype`` and ``rr:termType`` ``rr:IRI`` /
+``rr:Literal``.  Blank-node property lists, ``;``/``,`` lists, IRIs,
 prefixed names and string literals are handled by a small recursive-descent
 parser — enough to round-trip every mapping in the bundled testbeds.
+
+What the engine does not implement is refused with a ``SyntaxError`` that
+names the term, never dropped: ``rr:language``, ``rr:termType
+rr:BlankNode``, ``rr:graphMap``, a second ``rr:joinCondition``, and a second
+value of a single-valued term (``rr:class``, ``rr:predicate``,
+``rr:objectMap``, ``rr:datatype``, ...).
 """
 
 from __future__ import annotations
@@ -15,6 +22,8 @@ from __future__ import annotations
 import re
 
 from repro.rml.model import (
+    IRI,
+    LITERAL,
     JoinCondition,
     LogicalSource,
     MappingDocument,
@@ -147,17 +156,44 @@ class _Parser:
                 out.append(v)
         return out
 
-    def build_term_map(self, props) -> TermMap:
-        tpl = self._get(props, "template")
-        ref = self._get(props, "reference")
-        const = self._get(props, "constant")
-        if tpl:
-            return TermMap(template=self.expand(tpl[0]))
-        if ref:
-            return TermMap(reference=self.expand(ref[0]))
-        if const:
-            return TermMap(constant=self.expand(const[0]))
-        raise SyntaxError(f"term map without template/reference/constant: {props}")
+    def _one(self, props, key: str, where: str):
+        """The value of a single-valued term, None when absent; two or more
+        values are refused."""
+        vals = self._get(props, key)
+        if len(vals) > 1:
+            raise SyntaxError(f"{where}: more than one rr:{key} is not supported")
+        return vals[0] if vals else None
+
+    def _refuse(self, props, where: str, *keys: str) -> None:
+        for key in keys:
+            if self._get(props, key):
+                raise SyntaxError(f"{where}: rr:{key} is not supported")
+
+    def build_term_map(self, props, where: str, subject: bool = False) -> TermMap:
+        self._refuse(props, where, "language", "languageMap", "graphMap", "graph")
+        given = [(k, self._one(props, k, where)) for k in ("template", "reference", "constant")]
+        given = [(k, v) for k, v in given if v is not None]
+        if len(given) != 1:
+            raise SyntaxError(
+                f"{where}: a term map needs exactly one of template/reference/constant"
+            )
+        kind, value = given[0]
+        term_type = self._one(props, "termType", where)
+        if term_type is not None:
+            local = self.expand(term_type).rsplit("#", 1)[-1]
+            if local not in ("IRI", "Literal"):
+                raise SyntaxError(f"{where}: rr:termType rr:{local} is not supported")
+            term_type = IRI if local == "IRI" else LITERAL
+        elif subject and kind == "reference":
+            term_type = IRI  # R2RML §7.4: a subject map gives IRIs by default
+        datatype = self._one(props, "datatype", where)
+        try:
+            return TermMap(
+                **{kind: self.expand(value)}, term_type=term_type,
+                datatype=None if datatype is None else self.expand(datatype),
+            )
+        except ValueError as e:
+            raise SyntaxError(f"{where}: {e}") from None
 
     def build_triples_map(self, name: str, props) -> TriplesMap:
         ls_props = self._get(props, "logicalSource")[0]
@@ -174,42 +210,53 @@ class _Parser:
             iterator = self.expand(it[0])
         source = LogicalSource(path=self.expand(src_tok), fmt=fmt, iterator=iterator)
 
-        sm_props = self._get(props, "subjectMap")[0]
-        subject = self.build_term_map(sm_props)
-        cls = self._get(sm_props, "class")
-        subject_class = self.expand(cls[0]) if cls else None
+        self._refuse(props, name, "graphMap", "graph")
+        sm_props = self._one(props, "subjectMap", name)
+        if sm_props is None:
+            raise SyntaxError(f"{name}: no rr:subjectMap")
+        subject = self.build_term_map(sm_props, f"{name} subject map", subject=True)
+        cls = self._one(sm_props, "class", f"{name} subject map")
+        subject_class = self.expand(cls) if cls else None
 
         poms = []
         for pom_props in self._get(props, "predicateObjectMap"):
-            pred = self.expand(self._get(pom_props, "predicate")[0])
-            om_entries = self._get(pom_props, "objectMap")
-            if not om_entries:
-                raise SyntaxError(f"predicateObjectMap without objectMap in {name}")
-            om_props = om_entries[0]
-            parent = self._get(om_props, "parentTriplesMap")
+            where = f"{name} predicateObjectMap"
+            self._refuse(pom_props, where, "graphMap", "graph")
+            pred = self._one(pom_props, "predicate", where)
+            om_props = self._one(pom_props, "objectMap", where)
+            if pred is None or om_props is None:
+                raise SyntaxError(f"{where}: needs rr:predicate and rr:objectMap")
+            pred = self.expand(pred)
+            where = f"{name} object map of <{pred}>"
+            parent = self._one(om_props, "parentTriplesMap", where)
             if parent:
-                pname = str(parent[0])
+                pname = str(parent)
                 pname = (pname[1:-1] if pname.startswith("<") else pname).lstrip("#")
                 join = None
                 jc = self._get(om_props, "joinCondition")
+                if len(jc) > 1:
+                    raise SyntaxError(f"{where}: a second rr:joinCondition is not supported")
                 if jc:
-                    child = self.expand(self._get(jc[0], "child")[0])
-                    par = self.expand(self._get(jc[0], "parent")[0])
+                    child = self.expand(self._one(jc[0], "child", where))
+                    par = self.expand(self._one(jc[0], "parent", where))
                     join = JoinCondition(child=child, parent=par)
                 obj: TermMap | RefObjectMap = RefObjectMap(
                     parent_triples_map=pname, join=join
                 )
             else:
-                obj = self.build_term_map(om_props)
+                obj = self.build_term_map(om_props, where)
             poms.append(PredicateObjectMap(predicate=pred, object_map=obj))
 
-        return TriplesMap(
-            name=name,
-            source=source,
-            subject=subject,
-            subject_class=subject_class,
-            poms=tuple(poms),
-        )
+        try:
+            return TriplesMap(
+                name=name,
+                source=source,
+                subject=subject,
+                subject_class=subject_class,
+                poms=tuple(poms),
+            )
+        except ValueError as e:
+            raise SyntaxError(str(e)) from None
 
 
 def parse(text: str) -> MappingDocument:

@@ -2,7 +2,7 @@
 
 from __future__ import annotations
 
-from repro.rml.model import MappingDocument, RefObjectMap, TermMap, TriplesMap
+from repro.rml.model import IRI, MappingDocument, RefObjectMap, TermMap, TriplesMap
 
 _PREFIXES = """\
 @prefix rr: <http://www.w3.org/ns/r2rml#> .
@@ -14,10 +14,16 @@ _PREFIXES = """\
 
 def _term(om: TermMap, indent: str) -> str:
     if om.template is not None:
-        return f'{indent}rr:template "{om.template}"'
-    if om.reference is not None:
-        return f'{indent}rml:reference "{om.reference}"'
-    return f'{indent}rr:constant "{om.constant}"'
+        out = f'{indent}rr:template "{om.template}"'
+    elif om.reference is not None:
+        out = f'{indent}rml:reference "{om.reference}"'
+    else:
+        out = f'{indent}rr:constant "{om.constant}"'
+    if om.term_type is not None:
+        out += " ; rr:termType " + ("rr:IRI" if om.term_type == IRI else "rr:Literal")
+    if om.datatype is not None:
+        out += f" ; rr:datatype <{om.datatype}>"
+    return out
 
 
 def _triples_map(tm: TriplesMap) -> str:
