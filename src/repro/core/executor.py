@@ -28,7 +28,9 @@ counters ``ingest.host_syncs`` (blocking device-to-host reads),
 ``rows × K`` probe lanes, and the valid ones among them), and
 ``ingest.null_terms`` (source rows an op masks because a term it reads —
 subject, object or child join key — is NULL; counted once a predicate
-completes, so a replay does not count twice).
+completes, so a replay does not count twice).  The N-Triples writer
+counts ``emit.lines`` (lines written) and ``emit.terms_rendered`` (the
+distinct terms it rendered, each once).
 """
 
 from __future__ import annotations
@@ -48,7 +50,7 @@ from repro.core.hashset import next_pow2
 from repro.data import pipeline
 from repro.data.encoder import NULL_ID, Dictionary, join_columns
 from repro.data.sources import SourceCache
-from repro.data.terms import render_term
+from repro.data.terms import render_terms
 from repro.rml.model import MappingDocument
 
 
@@ -185,20 +187,50 @@ class KGResult:
     def n_triples(self) -> int:
         return sum(len(t["subj_val"]) for t in self.triples.values())
 
-    def iter_ntriples(self):
-        d = self.dictionary
+    def _term_table(self, encode: bool) -> "tuple[np.ndarray, np.ndarray]":
+        """The KG's distinct ``(pattern, value)`` terms as sorted int64 keys,
+        and each one rendered once (UTF-8 bytes with ``encode``)."""
+        keys = np.unique(np.concatenate(
+            [np.unique(_term_keys(t, side)) for t in self.triples.values()
+             for side in ("subj", "obj")] or [np.zeros(0, np.int64)]
+        ))
+        rendered = render_terms(
+            self.dictionary, keys >> 32, (keys & 0xFFFFFFFF).astype(np.int32)
+        )
+        obs.get_registry().inc("emit.terms_rendered", len(rendered))
+        table = np.empty(len(rendered), dtype=object)
+        table[:] = [r.encode("utf-8") for r in rendered] if encode else rendered
+        return keys, table
+
+    def _term_chunks(self, encode: bool):
+        """``(predicate, subjects, objects)`` in chunks of at most
+        ``_CHUNK_LINES`` triples: predicates in ``triples`` order, rows in
+        array order, terms from :meth:`_term_table`, found by binary search,
+        so no per-line array of the whole KG is held."""
+        keys, table = self._term_table(encode)
         for pred, t in self.triples.items():
-            for i in range(len(t["subj_val"])):
-                s = _render(d, int(t["subj_pat"][i]), int(t["subj_val"][i]))
-                o = _render(d, int(t["obj_pat"][i]), int(t["obj_val"][i]))
-                yield f"{s} <{pred}> {o} ."
+            for a in range(0, len(t["subj_val"]), _CHUNK_LINES):
+                rows = slice(a, a + _CHUNK_LINES)
+                subj = table[np.searchsorted(keys, _term_keys(t, "subj", rows))]
+                obj = table[np.searchsorted(keys, _term_keys(t, "obj", rows))]
+                yield pred, subj.tolist(), obj.tolist()
+
+    def iter_ntriples(self):
+        for pred, subj, obj in self._term_chunks(encode=False):
+            mid = f" <{pred}> "
+            for s, o in zip(subj, obj):
+                yield f"{s}{mid}{o} ."
 
     def write_ntriples(self, path: str) -> int:
         n = 0
-        with open(path, "w", encoding="utf-8") as f:
-            for line in self.iter_ntriples():
-                f.write(line + "\n")
-                n += 1
+        with open(path, "wb") as f:
+            for pred, subj, obj in self._term_chunks(encode=True):
+                parts = [b"", f" <{pred}> ".encode("utf-8"), b"", b" .\n"] * len(subj)
+                parts[0::4] = subj
+                parts[2::4] = obj
+                f.write(b"".join(parts))
+                n += len(subj)
+        obs.get_registry().inc("emit.lines", n)
         return n
 
     def sorted_ntriples(self) -> list[str]:
@@ -264,8 +296,16 @@ def _sources_by_key(doc: MappingDocument) -> dict:
     }
 
 
-# shared with the repro.kg decode path: full N-Triples escaping, not just `"`
-_render = render_term
+# the N-Triples writer's lines a chunk: one b"".join and one write each
+_CHUNK_LINES = 1 << 16
+
+
+def _term_keys(t: dict, side: str, rows: slice = slice(None)) -> np.ndarray:
+    """A predicate's ``side`` ("subj" or "obj") terms as int64
+    ``pattern << 32 | value`` keys."""
+    pat = np.asarray(t[f"{side}_pat"][rows], np.int64)
+    return (pat << 32) | (np.asarray(t[f"{side}_val"][rows], np.int64) & 0xFFFFFFFF)
+
 
 _END = object()
 
@@ -288,7 +328,8 @@ def _touch_counters() -> None:
     replay reads 0 rather than nothing."""
     reg = obs.get_registry()
     for name in ("ingest.host_syncs", "ingest.replays", "ingest.join_lanes",
-                 "ingest.join_candidates", "ingest.null_terms"):
+                 "ingest.join_candidates", "ingest.null_terms", "emit.lines",
+                 "emit.terms_rendered"):
         reg.counter(name)
 
 

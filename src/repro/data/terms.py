@@ -3,7 +3,8 @@
 A term leaves the engine as a *(pattern id, value id)* pair into the global
 :class:`~repro.data.encoder.Dictionary`; this module is the single place that
 turns the pair back into a concrete N-Triples term string.  It is shared by
-``core.executor`` (the N-Triples dump) and ``repro.kg`` (query-time binding
+``core.executor`` (the N-Triples dump, through the batch form
+:func:`render_terms`) and ``repro.kg`` (query-time binding
 decode), so both emit byte-identical — and *valid* — N-Triples: literals get
 full string escaping (backslash, quote, and control characters), not just
 ``"``.  It lives beside the encoder in ``repro.data`` so the dependency DAG
@@ -14,7 +15,9 @@ from __future__ import annotations
 
 import re
 
-from repro.data.encoder import Dictionary, render_template
+import numpy as np
+
+from repro.data.encoder import _SEP, Dictionary, render_template
 
 # RFC 3987 iunreserved (ALPHA / DIGIT / "-" / "." / "_" / "~" / ucschar), and
 # the multi-column separator, which render_template splits on
@@ -65,17 +68,25 @@ def unescape_literal(s: str) -> str:
     return _UNESCAPE.sub(repl, s)
 
 
-def _pct(m: re.Match) -> str:
-    return "".join(f"%{b:02X}" for b in m.group(0).encode("utf-8"))
-
-
 def iri_safe(value: str) -> str:
     """R2RML §7.3's IRI-safe form: every character outside RFC 3987
     ``iunreserved`` percent-encoded as UTF-8 octets.  A value that needs no
     encoding is returned as it is after one regex scan."""
-    if _IRI_UNSAFE.search(value) is None:
-        return value
-    return _IRI_UNSAFE.sub(_pct, value)
+    unsafe = set(_IRI_UNSAFE.findall(value))
+    # '%' first: the codes that replace the others bring '%' in
+    for ch in sorted(unsafe, key=lambda c: c != "%"):
+        value = value.replace(ch, "".join(f"%{b:02X}" for b in ch.encode("utf-8")))
+    return value
+
+
+def _iri_safe_all(values: list[str]) -> list[str]:
+    """:func:`iri_safe` of each value, as one pass over the values joined
+    by a newline, which encodes to ``%0A``: a ``%0A`` of the output can
+    only be a separator, since a value's own ``%`` becomes ``%25``."""
+    joined = "\n".join(values)
+    if joined.count("\n") != len(values) - 1:  # a value holds a newline
+        return [iri_safe(v) for v in values]
+    return iri_safe(joined).split("%0A")
 
 
 def render_term(d: Dictionary, pat_id: int, val_id: int) -> str:
@@ -93,6 +104,42 @@ def render_term(d: Dictionary, pat_id: int, val_id: int) -> str:
     if kind == "typ":
         return f'"{escape_literal(pattern)}"^^<{datatype}>'
     return '"' + escape_literal(pattern) + '"'
+
+
+def render_terms(d: Dictionary, pat_ids: np.ndarray, val_ids: np.ndarray) -> list[str]:
+    """:func:`render_term` of each ``(pat_ids[i], val_ids[i])`` pair, in
+    order.  Each pattern is decoded and split once, and its values are
+    filled in one pass: the writer's way of rendering a KG's distinct
+    terms."""
+    pat_ids, val_ids = np.asarray(pat_ids), np.asarray(val_ids)
+    strings = d.strings()
+    out = np.empty(len(pat_ids), dtype=object)
+    order = np.argsort(pat_ids, kind="stable")
+    cuts = np.flatnonzero(np.diff(pat_ids[order])) + 1
+    for rows in np.split(order, cuts) if len(order) else ():
+        kind, pattern = strings[pat_ids[rows[0]]].split(":", 1)
+        if kind == "typ":
+            datatype, pattern = pattern.split(" ", 1)
+        chunks = pattern.split("{}")
+        if len(chunks) == 1:
+            out[rows] = render_term(d, int(pat_ids[rows[0]]), 0)
+            continue
+        values = [strings[v] for v in val_ids[rows].tolist()]
+        if kind == "iri":
+            values = _iri_safe_all(values)
+        if len(chunks) == 2:
+            head, tail = chunks
+            filled = [head + v + tail if _SEP not in v else render_template(pattern, v)
+                      for v in values]
+        else:
+            filled = [render_template(pattern, v) for v in values]
+        if kind in ("iri", "ref"):
+            out[rows] = [f"<{s}>" for s in filled]
+        elif kind == "typ":
+            out[rows] = [f'"{escape_literal(s)}"^^<{datatype}>' for s in filled]
+        else:
+            out[rows] = ['"' + escape_literal(s) + '"' for s in filled]
+    return out.tolist()
 
 
 def split_typed(token: str) -> "tuple[str, str] | None":
