@@ -8,10 +8,14 @@ Pipeline per run (paper Fig. 2):
   the Knowledge Graph Creator appends the ``is_new`` triples incrementally.
 
 Engines:
-  * ``optimized`` — the SDM-RDFizer operators (PTT incremental dedup, PJTT
-    index join).
-  * ``naive``     — SDM-RDFizer⁻: generate everything, nested-loop joins,
-    one merge-sort dedup per predicate at the end.
+  * ``optimized`` — the SDM-RDFizer operators: PTT incremental dedup
+    (``_dedup_step``) and the sorted PJTT index join (``_ojm_sorted_step``).
+    One predicate runner, :meth:`Engine._run_predicates`, drives them for
+    either input: eager (each op's encoded columns, cached per source and
+    column set) or streamed (``stream=True``: each op's ``repro.stream``
+    blocks, split into device batches).
+  * ``naive``     — SDM-RDFizer⁻, the reference: generate everything,
+    nested-loop joins, one merge-sort dedup per predicate at the end.
 
 Both produce identical knowledge graphs (asserted in tests); they differ
 only in operation count / wall-time, which is the paper's claim.
@@ -87,28 +91,6 @@ def _ojm_sorted_step(
     )
 
 
-@partial(jax.jit, static_argnums=(10,), donate_argnums=(0, 1))
-def _ojm_hash_step(
-    hi, lo, tkey, tstart, tcount, ssubj, subj_tmpl, subj_vals, pred_id, obj_tmpl,
-    max_matches, child_keys, valid,
-):
-    """OJM step, hash PJTT."""
-    pr = pjtt.probe_hash(
-        pjtt.PJTTHash(tkey, tstart, tcount, ssubj), child_keys, max_matches
-    )
-    m, K = pr.subjects.shape
-    subj = jnp.broadcast_to(subj_vals[:, None], (m, K)).reshape(-1)
-    obj = pr.subjects.reshape(-1)
-    v = (pr.valid & valid[:, None]).reshape(-1)
-    khi, klo = hashing.triple_key(subj_tmpl, subj, pred_id, obj_tmpl, obj)
-    res = hashset.insert_masked(hashset.HashSet(hi, lo), khi, klo, v)
-    return (
-        res.table.hi, res.table.lo,
-        res.is_new.reshape(m, K), pr.subjects, v.reshape(m, K),
-        res.overflowed, pr.truncated,
-    )
-
-
 @jax.jit
 def _naive_keys_step(subj_tmpl, subj_vals, pred_id, obj_tmpl, obj_vals):
     return hashing.triple_key(subj_tmpl, subj_vals, pred_id, obj_tmpl, obj_vals)
@@ -126,12 +108,10 @@ def _naive_dedup(khi, klo, valid):
 
 @jax.jit
 def _build_sorted(keys, subjects):
+    """The PJTT of one parent: each distinct ``(key, subject)`` pair once,
+    so a probe's width is the largest number of distinct subjects of one
+    key, and ``skeys`` sizes the joins."""
     return pjtt.build_sorted(keys, subjects)
-
-
-@jax.jit
-def _hash_index(srt):
-    return pjtt.hash_index(srt)
 
 
 @jax.jit
@@ -333,14 +313,6 @@ def _touch_counters() -> None:
         reg.counter(name)
 
 
-def _build_index(kd, sd, join_strategy: str):
-    """The PJTT of one parent and the sorted keys its spans count: each
-    distinct ``(key, subject)`` pair once, so a probe's width is the
-    largest number of distinct subjects of one key."""
-    srt = _build_sorted(kd, sd)
-    return (_hash_index(srt) if join_strategy == "hash" else srt), srt.skeys
-
-
 # --------------------------------------------------------------------------
 # the engine
 # --------------------------------------------------------------------------
@@ -349,7 +321,6 @@ def _build_index(kd, sd, join_strategy: str):
 @dataclasses.dataclass
 class EngineConfig:
     engine: str = "optimized"        # optimized | naive
-    join_strategy: str = "sorted"    # sorted | hash
     batch_size: int = 1 << 16
     load_factor: float = 0.6
     max_matches: int | None = None   # None -> derived from true max span
@@ -449,9 +420,8 @@ class Engine:
                     indexes[pkey] = (kd, sd)
                     span_keys[pkey] = jnp.sort(kd)
                 else:
-                    indexes[pkey], span_keys[pkey] = _build_index(
-                        kd, sd, cfg.join_strategy
-                    )
+                    indexes[pkey] = _build_sorted(kd, sd)
+                    span_keys[pkey] = indexes[pkey].skeys
 
         # ---- per-predicate candidate estimate -> PTT capacity
         stats: dict[str, PredicateStats] = {}
@@ -481,30 +451,44 @@ class Engine:
             pred_candidates[pred] = total
             stats[pred] = PredicateStats(kind=kind)
 
+        def op_input(op):
+            """An op's cached columns in fixed-shape batches."""
+            subj_vals = values_for(op.source_key, op.subj_columns)
+            cols = {"subj": subj_vals}
+            n_parent = 0
+            if op.kind == "OJM":
+                cols["jkey"] = values_for(op.source_key, (op.join_child_column,))
+                n_parent = len(
+                    values_for(op.parent_source_key, (op.parent_join_column,))
+                )
+            elif op.kind in ("SOM", "ORM"):
+                cols["obj"] = values_for(op.source_key, op.obj_columns)
+            else:  # CLASS: constant object
+                cols["obj"] = np.zeros_like(subj_vals)
+            n = len(subj_vals)
+            bs = min(cfg.batch_size, pipeline.pick_batch_size(n))
+            return n_parent, n, pipeline.batches(cols, bs)
+
         # ---- run the ops: group-by-group along the mapping plan's DAG
         # when planning is on (groups are disjoint in predicates and
         # sources, so this only reorders work), else one flat pass
         triples_out: dict[str, dict[str, list[np.ndarray]]] = {}
         if mplan is not None:
-            schedule = [
-                (g, [(p, exec_plan.by_predicate[p]) for p in g.predicates])
-                for g in mplan.groups
-            ]
+            schedule = [(g, list(g.predicates)) for g in mplan.groups]
         else:
-            schedule = [(None, list(exec_plan.by_predicate.items()))]
-        for g, pred_items in schedule:
+            schedule = [(None, list(exec_plan.by_predicate))]
+        for g, preds in schedule:
             span_args = {"group": g.index} if g is not None else {}
             with obs.span("plan_group", cat="plan", **span_args):
                 if cfg.engine == "optimized":
-                    self._run_optimized(
-                        exec_plan, values_for, indexes, pred_candidates,
+                    self._run_predicates(
+                        exec_plan, preds, op_input, indexes, pred_candidates,
                         op_spans, stats, triples_out, dct,
-                        pred_items=pred_items,
                     )
                 else:
                     self._run_naive(
-                        exec_plan, values_for, indexes, op_spans, stats,
-                        triples_out, dct, pred_items=pred_items,
+                        exec_plan, preds, values_for, indexes, op_spans,
+                        stats, triples_out, dct,
                     )
 
         return KGResult(
@@ -515,84 +499,77 @@ class Engine:
             engine=cfg.engine,
         )
 
-    # -- shared per-batch step (eager and streamed paths) ----------------------
+    # -- the optimized engine: one predicate runner for both inputs -----------
 
-    def _consume_batch(
-        self, op, spat, pid, opat, hi, lo, batch, index, K, out, st,
-    ):
+    def _step(self, op, spat, pid, opat, hi, lo, batch, index, K, out, st):
         """Push one fixed-shape padded batch through the jitted step for
         ``op``; appends emitted triples to ``out`` and accumulates ``st``.
         Returns ``(hi, lo, overflowed)``."""
         with obs.span("ingest.step", op=op.kind, rows=len(batch.valid)):
-            return self._step(op, spat, pid, opat, hi, lo, batch, index, K, out, st)
-
-    def _step(self, op, spat, pid, opat, hi, lo, batch, index, K, out, st):
-        # a lane whose subject, object or child key is NULL is no candidate
-        other = batch.arrays["jkey" if op.kind == "OJM" else "obj"]
-        live = batch.valid & (batch.arrays["subj"] != NULL_ID) & (other != NULL_ID)
-        st.n_null += int(batch.valid.sum() - live.sum())
-        valid = jnp.asarray(live)
-        sv = jnp.asarray(batch.arrays["subj"])
-        if op.kind == "OJM":
-            ck = jnp.asarray(batch.arrays["jkey"])
-            if isinstance(index, pjtt.PJTTSorted):
+            # a lane whose subject, object or child key is NULL is no candidate
+            other = batch.arrays["jkey" if op.kind == "OJM" else "obj"]
+            live = (
+                batch.valid & (batch.arrays["subj"] != NULL_ID) & (other != NULL_ID)
+            )
+            st.n_null += int(batch.valid.sum() - live.sum())
+            valid = jnp.asarray(live)
+            sv = jnp.asarray(batch.arrays["subj"])
+            if op.kind == "OJM":
+                ck = jnp.asarray(batch.arrays["jkey"])
                 hi, lo, is_new, psubj, v, ovf, trunc = _ojm_sorted_step(
                     hi, lo, index.skeys, index.ssubj, spat, sv, pid,
                     opat, K, ck, valid,
                 )
+                outputs = (trunc, is_new, v, psubj, ovf)
             else:
-                hi, lo, is_new, psubj, v, ovf, trunc = _ojm_hash_step(
-                    hi, lo, index.tkey, index.tstart, index.tcount,
-                    index.ssubj, spat, sv, pid, opat, K, ck, valid,
+                ov = jnp.asarray(batch.arrays["obj"])
+                hi, lo, is_new, ovf = _dedup_step(
+                    hi, lo, spat, sv, pid, opat, ov, valid
                 )
-            outputs = (trunc, is_new, v, psubj, ovf)
-        else:
-            ov = jnp.asarray(batch.arrays["obj"])
-            hi, lo, is_new, ovf = _dedup_step(
-                hi, lo, spat, sv, pid, opat, ov, valid
-            )
-            outputs = (is_new, ovf)
-        reg = obs.get_registry()
-        with obs.span("ingest.sync"):
-            got = [np.asarray(x) for x in outputs]  # one blocking read each
-            reg.inc("ingest.host_syncs", len(got))
-        with obs.span("ingest.compact"):
-            if op.kind == "OJM":
-                trunc, is_new, v, psubj, ovf = got
-                if trunc:
-                    raise RuntimeError(
-                        f"PJTT span exceeded max_matches={K}; "
-                        "re-run with a larger max_matches"
-                    )
-                n_cand = int(v.sum())
-                st.n_candidates += n_cand
-                reg.inc("ingest.join_lanes", v.size)
-                reg.inc("ingest.join_candidates", n_cand)
-                rows, ks = np.nonzero(is_new & v)
-                obj = psubj[rows, ks]
-            else:
-                is_new, ovf = got
-                st.n_candidates += int(live.sum())
-                rows = np.nonzero(is_new & live)[0]
-                obj = batch.arrays["obj"][rows]
-            n_emit = len(rows)
-            out["subj_val"].append(batch.arrays["subj"][rows].astype(np.int32))
-            out["obj_val"].append(obj.astype(np.int32))
-            out["subj_pat"].append(np.full(n_emit, spat, np.int32))
-            out["obj_pat"].append(np.full(n_emit, opat, np.int32))
-            st.n_unique += n_emit
+                outputs = (is_new, ovf)
+            reg = obs.get_registry()
+            with obs.span("ingest.sync"):
+                got = [np.asarray(x) for x in outputs]  # one blocking read each
+                reg.inc("ingest.host_syncs", len(got))
+            with obs.span("ingest.compact"):
+                if op.kind == "OJM":
+                    trunc, is_new, v, psubj, ovf = got
+                    if trunc:
+                        raise RuntimeError(
+                            f"PJTT span exceeded max_matches={K}; "
+                            "re-run with a larger max_matches"
+                        )
+                    n_cand = int(v.sum())
+                    st.n_candidates += n_cand
+                    reg.inc("ingest.join_lanes", v.size)
+                    reg.inc("ingest.join_candidates", n_cand)
+                    rows, ks = np.nonzero(is_new & v)
+                    obj = psubj[rows, ks]
+                else:
+                    is_new, ovf = got
+                    st.n_candidates += int(live.sum())
+                    rows = np.nonzero(is_new & live)[0]
+                    obj = batch.arrays["obj"][rows]
+                n_emit = len(rows)
+                out["subj_val"].append(batch.arrays["subj"][rows].astype(np.int32))
+                out["obj_val"].append(obj.astype(np.int32))
+                out["subj_pat"].append(np.full(n_emit, spat, np.int32))
+                out["obj_pat"].append(np.full(n_emit, opat, np.int32))
+                st.n_unique += n_emit
         return hi, lo, bool(ovf)
 
-    # -- optimized engine ------------------------------------------------------
-
-    def _run_optimized(
-        self, exec_plan, values_for, indexes, pred_candidates, op_spans,
-        stats, triples_out, dct: Dictionary, pred_items=None,
+    def _run_predicates(
+        self, exec_plan, preds, op_input, indexes, pred_candidates, op_spans,
+        stats, triples_out, dct: Dictionary,
     ):
+        """Run ``preds`` through the jitted steps, each into a PTT sized
+        from its candidate count.  ``op_input(op)`` gives an op's
+        ``(n_parent, n_child, batches)``: the eager engine's cached columns
+        or the streamed engine's blocks.  A PTT overflow replays the
+        predicate with twice the capacity, calling ``op_input`` again."""
         cfg = self.config
-        if pred_items is None:
-            pred_items = exec_plan.by_predicate.items()
-        for pred, op_idxs in pred_items:
+        reg = obs.get_registry()
+        for pred in preds:
             cap = next_pow2(int(pred_candidates[pred] / cfg.load_factor) + 16)
             while True:  # overflow -> double capacity and replay the predicate
                 table = hashset.make(cap)
@@ -601,48 +578,34 @@ class Engine:
                 st = stats[pred]
                 st.n_candidates = st.n_unique = st.n_parent = st.n_child = st.n_null = 0
                 overflow = False
-                for i in op_idxs:
+                for i in exec_plan.by_predicate[pred]:
                     op = exec_plan.ops[i]
+                    # the dictionary's ids follow its encode order: these
+                    # three scalars first, then the op's columns
                     pid = np.int32(dct.encode_scalar(op.predicate))
                     spat = np.int32(dct.encode_scalar(op.subj_pattern))
                     opat = np.int32(dct.encode_scalar(op.obj_pattern))
-                    subj_vals = values_for(op.source_key, op.subj_columns)
-                    cols = {"subj": subj_vals}
+                    n_parent, n_child, batches = op_input(op)
+                    idx, K = None, 1
                     if op.kind == "OJM":
-                        cols["jkey"] = values_for(
-                            op.source_key, (op.join_child_column,)
-                        )
-                    elif op.kind in ("SOM", "ORM"):
-                        cols["obj"] = values_for(op.source_key, op.obj_columns)
-                    else:  # CLASS: constant object
-                        cols["obj"] = np.zeros_like(subj_vals)
-
-                    n = len(subj_vals)
-                    bs = min(cfg.batch_size, pipeline.pick_batch_size(n))
-                    K = 1
-                    if op.kind == "OJM":
-                        tot, mx = op_spans[i]
-                        K = cfg.max_matches or max(int(mx), 1)
-                        st.n_parent += (
-                            len(values_for(op.parent_source_key, (op.parent_join_column,)))
-                        )
-                        st.n_child += n
-                    idx = indexes[op.pjtt_key] if op.kind == "OJM" else None
-                    for batch in pipeline.batches(cols, bs):
-                        hi, lo, ovf = self._consume_batch(
+                        idx = indexes[op.pjtt_key]
+                        K = cfg.max_matches or max(int(op_spans[i][1]), 1)
+                        st.n_parent += n_parent
+                        st.n_child += n_child
+                    for batch in batches:
+                        hi, lo, overflow = self._step(
                             op, spat, pid, opat, hi, lo, batch, idx, K, out, st
                         )
-                        if ovf:
-                            overflow = True
+                        if overflow:
                             break
                     if overflow:
                         break
                 if not overflow:
                     triples_out[pred] = out
-                    obs.get_registry().inc("ingest.null_terms", st.n_null)
+                    reg.inc("ingest.null_terms", st.n_null)
                     break
-                obs.get_registry().inc("ingest.replays")
-                cap *= 2  # replay this predicate with a bigger table
+                reg.inc("ingest.replays")
+                cap *= 2
 
     # -- streamed optimized engine (repro.stream) ------------------------------
 
@@ -925,11 +888,11 @@ class Engine:
                 with obs.span("ingest.encode", source=psrc):
                     pkeys, psubj = parent_columns(psrc, pcol, pcols)
                 with obs.span("ingest.pjtt_build", pjtt=pkey):
-                    indexes[pkey], skeys = _build_index(
-                        jnp.asarray(pkeys), jnp.asarray(psubj), cfg.join_strategy
+                    indexes[pkey] = _build_sorted(
+                        jnp.asarray(pkeys), jnp.asarray(psubj)
                     )
                 with obs.span("ingest.size", pjtt=pkey):
-                    sorted_parent_keys[pkey] = np.asarray(skeys)
+                    sorted_parent_keys[pkey] = np.asarray(indexes[pkey].skeys)
                     reg.inc("ingest.host_syncs")
                 parent_counts[pkey] = len(pkeys)
                 row_counts[psrc] = len(pkeys)
@@ -991,64 +954,32 @@ class Engine:
             pred_candidates[pred] = total
 
         # ---- run the ops, block-at-a-time
+        def op_input(op):
+            """An op's blocks, each split into device batches."""
+            n_parent = n_child = 0
+            if op.kind == "OJM":
+                n_parent = parent_counts[op.pjtt_key]
+                n_child = row_counts[op.source_key]
+            blocks = (
+                op_blocks(op)
+                if mplan is not None
+                # the unplanned stream encodes as it reads
+                else _spanned("ingest.encode", op_dataset(op).iter_blocks(prefetch))
+            )
+            batches = (
+                batch
+                for block in blocks
+                for batch in pipeline.batches(block.columns, device_rows)
+            )
+            return n_parent, n_child, batches
+
         triples_out: dict[str, dict[str, list[np.ndarray]]] = {}
 
-        def run_predicates(pred_list) -> None:
-            for pred in pred_list:
-                op_idxs = exec_plan.by_predicate[pred]
-                cap = next_pow2(int(pred_candidates[pred] / cfg.load_factor) + 16)
-                while True:  # overflow -> double capacity, re-stream
-                    table = hashset.make(cap)
-                    hi, lo = table.hi, table.lo
-                    out = {
-                        k: []
-                        for k in ("subj_pat", "subj_val", "obj_pat", "obj_val")
-                    }
-                    st = stats[pred]
-                    st.n_candidates = st.n_unique = st.n_parent = st.n_child = st.n_null = 0
-                    overflow = False
-                    for i in op_idxs:
-                        op = exec_plan.ops[i]
-                        pid = np.int32(dct.encode_scalar(op.predicate))
-                        spat = np.int32(dct.encode_scalar(op.subj_pattern))
-                        opat = np.int32(dct.encode_scalar(op.obj_pattern))
-                        idx = None
-                        K = 1
-                        if op.kind == "OJM":
-                            idx = indexes[op.pjtt_key]
-                            _tot, mx = op_spans[i]
-                            K = cfg.max_matches or max(int(mx), 1)
-                            st.n_parent += parent_counts[op.pjtt_key]
-                            st.n_child += row_counts[op.source_key]
-                        blocks = (
-                            op_blocks(op)
-                            if mplan is not None
-                            # the unplanned stream encodes as it reads
-                            else _spanned(
-                                "ingest.encode", op_dataset(op).iter_blocks(prefetch)
-                            )
-                        )
-                        for block in blocks:
-                            for batch in pipeline.batches(
-                                block.columns, device_rows
-                            ):
-                                hi, lo, ovf = self._consume_batch(
-                                    op, spat, pid, opat, hi, lo, batch,
-                                    idx, K, out, st,
-                                )
-                                if ovf:
-                                    overflow = True
-                                    break
-                            if overflow:
-                                break
-                        if overflow:
-                            break
-                    if not overflow:
-                        triples_out[pred] = out
-                        reg.inc("ingest.null_terms", st.n_null)
-                        break
-                    reg.inc("ingest.replays")
-                    cap *= 2
+        def run_predicates(preds) -> None:
+            self._run_predicates(
+                exec_plan, preds, op_input, indexes, pred_candidates, op_spans,
+                stats, triples_out, dct,
+            )
 
         if mplan is None:
             build_pjtts(exec_plan.pjtt_builds.items())
@@ -1085,13 +1016,12 @@ class Engine:
     # -- naive engine ----------------------------------------------------------
 
     def _run_naive(
-        self, exec_plan, values_for, indexes, op_spans, stats, triples_out,
-        dct, pred_items=None,
+        self, exec_plan, preds, values_for, indexes, op_spans, stats,
+        triples_out, dct,
     ):
         cfg = self.config
-        if pred_items is None:
-            pred_items = exec_plan.by_predicate.items()
-        for pred, op_idxs in pred_items:
+        for pred in preds:
+            op_idxs = exec_plan.by_predicate[pred]
             khis, klos, valids = [], [], []
             svs, ovs, spats, opats = [], [], [], []
             st = stats[pred]

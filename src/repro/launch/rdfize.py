@@ -2,7 +2,7 @@
 
     PYTHONPATH=src python -m repro.launch.rdfize \
         --mapping mappings.ttl --data-root data/ --out kg.nt \
-        [--engine optimized|naive] [--join sorted|hash] \
+        [--engine optimized|naive] \
         [--stream] [--block-rows N] [--emit nt|kgz] \
         [--explain-mapping] [--no-mapping-plan]
 
@@ -49,7 +49,6 @@ def main(argv: list[str] | None = None) -> None:
     ap.add_argument("--data-root", default=".")
     ap.add_argument("--out", default=None, help="N-Triples output path")
     ap.add_argument("--engine", default="optimized", choices=("optimized", "naive"))
-    ap.add_argument("--join", default="sorted", choices=("sorted", "hash"))
     ap.add_argument("--batch-size", type=int, default=1 << 16)
     ap.add_argument("--stream", action="store_true",
                     help="block-streamed out-of-core ingestion (repro.stream)")
@@ -113,7 +112,6 @@ def main(argv: list[str] | None = None) -> None:
             doc,
             data_root=args.data_root,
             engine=args.engine,
-            join_strategy=args.join,
             batch_size=args.batch_size,
             stream=args.stream,
             block_rows=args.block_rows,

@@ -1,4 +1,5 @@
-"""Core engine: hash set, PJTT strategies, operators, and the paper's
+"""Core engine: hash set, PJTT, the engine's jitted steps against the
+naive reference's, the shared predicate runner, and the paper's
 operation-count (φ) model."""
 
 import collections
@@ -11,7 +12,10 @@ try:
 except ImportError:  # test image without hypothesis: seeded-example fallback
     from _hypothesis_shim import given, settings, st
 
-from repro.core import hashing, hashset, naive, operators, pjtt, ptt
+from repro import obs
+from repro.core import executor, hashing, hashset, pjtt
+from repro.core.executor import create_kg
+from repro.rml import generator
 
 
 def _keys(vals):
@@ -78,21 +82,16 @@ def test_hashset_distinct_count_property(n, k, seed):
 # --------------------------------------------------------------------- PJTT
 
 
-@pytest.mark.parametrize("strategy", ["sorted", "hash"])
 @pytest.mark.parametrize("n,m,keys", [(50, 30, 5), (1000, 700, 40), (64, 64, 1)])
-def test_pjtt_matches_python_join(strategy, n, m, keys):
+def test_pjtt_matches_python_join(n, m, keys):
     rng = np.random.default_rng(n + m)
     pk = rng.integers(0, keys, n).astype(np.int32)
     ps = rng.integers(0, 10000, n).astype(np.int32)
     ck = rng.integers(0, keys + 2, m).astype(np.int32)
     K = int(np.bincount(pk, minlength=keys).max()) + 1
 
-    if strategy == "sorted":
-        idx = pjtt.build_sorted(jnp.asarray(pk), jnp.asarray(ps))
-        pr = pjtt.probe_sorted(idx, jnp.asarray(ck), K)
-    else:
-        idx = pjtt.build_hash(jnp.asarray(pk), jnp.asarray(ps))
-        pr = pjtt.probe_hash(idx, jnp.asarray(ck), K)
+    idx = pjtt.build_sorted(jnp.asarray(pk), jnp.asarray(ps))
+    pr = pjtt.probe_sorted(idx, jnp.asarray(ck), K)
     assert not bool(pr.truncated)
 
     from collections import defaultdict
@@ -123,11 +122,9 @@ def test_pjtt_truncation_flag():
     assert bool(pr.truncated)
 
 
-def _probe(strategy, pk, ps, ck, K):
+def _probe(pk, ps, ck, K):
     pk, ps, ck = (jnp.asarray(np.asarray(a, np.int32)) for a in (pk, ps, ck))
-    if strategy == "sorted":
-        return pjtt.probe_sorted(pjtt.build_sorted(pk, ps), ck, K)
-    return pjtt.probe_hash(pjtt.build_hash(pk, ps), ck, K)
+    return pjtt.probe_sorted(pjtt.build_sorted(pk, ps), ck, K)
 
 
 def _distinct_subjects(pk, ps):
@@ -137,9 +134,8 @@ def _distinct_subjects(pk, ps):
     return d
 
 
-@pytest.mark.parametrize("strategy", ["sorted", "hash"])
 @pytest.mark.parametrize("n,keys,subjects", [(200, 5, 4), (1000, 30, 3), (333, 1, 7)])
-def test_pjtt_span_holds_distinct_pairs(strategy, n, keys, subjects):
+def test_pjtt_span_holds_distinct_pairs(n, keys, subjects):
     """Parent rows that repeat (key, subject) pairs: a probe's span holds
     each key's distinct subjects once, ascending and packed at the front,
     so K = the largest distinct count probes without truncation."""
@@ -150,7 +146,7 @@ def test_pjtt_span_holds_distinct_pairs(strategy, n, keys, subjects):
     d = _distinct_subjects(pk, ps)
     K = max(len(v) for v in d.values())
     assert K < np.bincount(pk).max()  # the raw spans are wider
-    pr = _probe(strategy, pk, ps, ck, K)
+    pr = _probe(pk, ps, ck, K)
     assert not bool(pr.truncated)
     subs, valid = np.asarray(pr.subjects), np.asarray(pr.valid)
     for i, k in enumerate(ck.tolist()):
@@ -158,32 +154,28 @@ def test_pjtt_span_holds_distinct_pairs(strategy, n, keys, subjects):
         assert valid[i].sum() == len(want)
         assert valid[i][: len(want)].all()
         assert subs[i][: len(want)].tolist() == want
-    assert bool(_probe(strategy, pk, ps, ck, K - 1).truncated)
+    assert bool(_probe(pk, ps, ck, K - 1).truncated)
 
 
-@pytest.mark.parametrize("strategy", ["sorted", "hash"])
-def test_pjtt_raw_span_beyond_K_distinct_within(strategy):
+def test_pjtt_raw_span_beyond_K_distinct_within():
     """Key 1 has six parent rows but three distinct subjects: K = 3 holds
     it, and the key after it starts right behind its distinct span."""
     pk = [1, 1, 1, 1, 1, 1, 2, 2, 2]
     ps = [7, 9, 7, 8, 9, 7, 5, 5, 5]
-    pr = _probe(strategy, pk, ps, [1, 2, 3], 3)
+    pr = _probe(pk, ps, [1, 2, 3], 3)
     assert not bool(pr.truncated)
     subs, valid = np.asarray(pr.subjects), np.asarray(pr.valid)
     assert subs[0].tolist() == [7, 8, 9] and valid[0].all()
     assert subs[1][0] == 5 and valid[1].tolist() == [True, False, False]
     assert not valid[2].any()
-    assert bool(_probe(strategy, pk, ps, [1, 2, 3], 2).truncated)
+    assert bool(_probe(pk, ps, [1, 2, 3], 2).truncated)
 
 
 def test_engines_agree_when_parents_repeat_pairs():
     """Parent rows repeat, so the raw widest span is wider than the
-    distinct one.  With max_matches left to the engine, the eager sorted
-    and hash engines (sized by distinct spans), the streamed engine and
-    the naive engine (sized by raw spans) give the same KG and none
-    reports a truncated span."""
-    from repro.core.executor import create_kg
-    from repro.rml import generator
+    distinct one.  With max_matches left to the engine, the eager and
+    streamed engines (sized by distinct spans) and the naive engine (sized
+    by raw spans) give the same KG and none reports a truncated span."""
     from repro.rml.plan import build_plan
 
     tb = generator.make_testbed("OJM", 800, 0.75, n_poms=2, seed=3)
@@ -197,34 +189,46 @@ def test_engines_agree_when_parents_repeat_pairs():
         ck = set(tables[op.source_key][op.join_child_column])
         assert max(raw[k] for k in ck) > max(len(d[k]) for k in ck)
     runs = {
-        "sorted": dict(join_strategy="sorted"),
-        "hash": dict(join_strategy="hash"),
+        "eager": dict(),
         "stream": dict(stream=True, block_rows=300),
         "naive": dict(engine="naive"),
     }
     kgs = {k: create_kg(tb.doc, tables=tables, **cfg) for k, cfg in runs.items()}
-    assert kgs["sorted"].as_set() == kgs["hash"].as_set() == kgs["naive"].as_set()
-    want = kgs["sorted"].sorted_ntriples()
+    assert kgs["eager"].as_set() == kgs["naive"].as_set()
+    want = kgs["eager"].sorted_ntriples()
     assert len(want) > 0
     assert all(kg.sorted_ntriples() == want for kg in kgs.values())
 
 
-# ---------------------------------------------------------------- operators
+# ------------------------------------------- jitted steps against the naive's
+
+# a rule's term ids: subject pattern, predicate, object pattern
+SPAT, PID, OPAT = np.int32(1), np.int32(2), np.int32(3)
+
+
+def _naive_unique(subj, obj, valid):
+    """The naive engine's count of distinct triples: every candidate key,
+    then one sort-dedup."""
+    khi, klo = executor._naive_keys_step(
+        SPAT, jnp.asarray(subj), PID, OPAT, jnp.asarray(obj)
+    )
+    return int(executor._naive_dedup(khi, klo, jnp.asarray(valid)).n_unique)
 
 
 def test_som_vs_naive_identical_triples():
     rng = np.random.default_rng(0)
     subj = rng.integers(0, 50, 500).astype(np.int32)
     obj = rng.integers(0, 20, 500).astype(np.int32)
-    p = operators.StaticTripleParams(subj_tmpl=1, pred_id=2, obj_tmpl=3)
 
-    table = ptt.make(600)
-    r = operators.som(table, jnp.asarray(subj), jnp.asarray(obj), p)
-    n_opt = int(np.asarray(r.is_new).sum())
+    table = hashset.make(1024)
+    _hi, _lo, is_new, ovf = executor._dedup_step(
+        table.hi, table.lo, SPAT, jnp.asarray(subj), PID, OPAT, jnp.asarray(obj),
+        jnp.ones(500, bool),
+    )
+    assert not bool(ovf)
+    n_opt = int(np.asarray(is_new).sum())
 
-    keys = operators.naive_som_keys(jnp.asarray(subj), jnp.asarray(obj), p)
-    dd = operators.naive_dedup(keys)
-    assert n_opt == int(dd.n_unique)
+    assert n_opt == _naive_unique(subj, obj, np.ones(500, bool))
     assert n_opt == len({(s, o) for s, o in zip(subj.tolist(), obj.tolist())})
 
 
@@ -235,32 +239,70 @@ def test_ojm_index_join_vs_nested_loop():
     ck = rng.integers(0, 22, 150).astype(np.int32)
     csub = rng.integers(0, 100, 150).astype(np.int32)
     K = int(np.bincount(pk).max()) + 1
-    p = operators.StaticTripleParams(subj_tmpl=1, pred_id=2, obj_tmpl=3)
 
-    idx = pjtt.build_sorted(jnp.asarray(pk), jnp.asarray(psub))
-    r = operators.ojm(
-        ptt.make(200 * K), idx, jnp.asarray(csub), jnp.asarray(ck), p, K
+    idx = executor._build_sorted(jnp.asarray(pk), jnp.asarray(psub))
+    table = hashset.make(hashset.next_pow2(200 * K))
+    _hi, _lo, is_new, _psubj, v, ovf, trunc = executor._ojm_sorted_step(
+        table.hi, table.lo, idx.skeys, idx.ssubj, SPAT, jnp.asarray(csub), PID,
+        OPAT, K, jnp.asarray(ck), jnp.ones(150, bool),
     )
-    n_opt = int(np.asarray(r.is_new & r.valid).sum())
+    assert not bool(ovf) and not bool(trunc)
+    n_opt = int(np.asarray(is_new & v).sum())
 
-    keys, _, trunc = operators.naive_ojm_keys(
-        jnp.asarray(pk), jnp.asarray(psub), jnp.asarray(csub), jnp.asarray(ck), p, K
-    )
-    assert not bool(trunc)
-    dd = operators.naive_dedup(keys)
-    assert n_opt == int(dd.n_unique)
+    jr = executor._naive_join_step(jnp.asarray(pk), jnp.asarray(psub), K, jnp.asarray(ck))
+    assert not bool(jr.truncated)
+    subj = np.broadcast_to(csub[:, None], (150, K)).reshape(-1)
+    obj = np.asarray(jr.subjects).reshape(-1)
+    assert n_opt == _naive_unique(subj, obj, np.asarray(jr.valid).reshape(-1))
 
     # python oracle
     pairs = set()
-    from collections import defaultdict
-
-    d = defaultdict(set)
-    for k, s in zip(pk.tolist(), psub.tolist()):
-        d[k].add(s)
+    d = _distinct_subjects(pk.tolist(), psub.tolist())
     for k, s in zip(ck.tolist(), csub.tolist()):
         for ps_ in d.get(k, ()):
             pairs.add((s, ps_))
     assert n_opt == len(pairs)
+
+
+# ------------------------------------------------- the shared predicate runner
+
+
+def _testbed_run(kind, **cfg):
+    tb = generator.make_testbed(kind, 1500, 0.75, n_poms=2, seed=11)
+    tables = {"csv:child.csv": tb.child}
+    if tb.parent is not None:
+        tables["csv:parent.csv"] = tb.parent
+    return create_kg(tb.doc, tables=tables, **cfg)
+
+
+def _replays():
+    return obs.get_registry().snapshot()["counters"].get("ingest.replays", 0)
+
+
+@pytest.mark.parametrize("kind", ["SOM", "ORM", "OJM"])
+def test_eager_and_streamed_engines_report_the_same_stats(kind):
+    """Both inputs go through one predicate runner: every field of every
+    predicate's stats agrees, and so does the KG."""
+    eager = _testbed_run(kind)
+    streamed = _testbed_run(kind, stream=True, block_rows=700)
+    assert streamed.engine == "stream"
+    assert eager.stats == streamed.stats
+    assert any(st.n_candidates > st.n_unique > 0 for st in eager.stats.values())
+    assert eager.sorted_ntriples() == streamed.sorted_ntriples()
+
+
+@pytest.mark.parametrize("stream", [False, True], ids=["eager", "stream"])
+def test_replay_keeps_the_stats(stream):
+    """A PTT sized at 8 candidates a slot overflows: the runner replays the
+    predicate with a doubled table, and what it reports and emits is what
+    a run without a replay reports and emits."""
+    cfg = dict(stream=stream, block_rows=700)
+    want = _testbed_run("OJM", **cfg)
+    before = _replays()
+    got = _testbed_run("OJM", load_factor=8.0, **cfg)
+    assert _replays() - before >= 1
+    assert got.stats == want.stats
+    assert got.sorted_ntriples() == want.sorted_ntriples()
 
 
 # ------------------------------------------------------------------ φ model

@@ -16,16 +16,11 @@ def test_engines_produce_identical_kg(kind, dup):
     if tb.parent is not None:
         tables["csv:parent.csv"] = tb.parent
     results = {
-        name: create_kg(tb.doc, tables=tables, engine=eng, join_strategy=js)
-        for name, (eng, js) in {
-            "opt-sorted": ("optimized", "sorted"),
-            "opt-hash": ("optimized", "hash"),
-            "naive": ("naive", "sorted"),
-        }.items()
+        eng: create_kg(tb.doc, tables=tables, engine=eng)
+        for eng in ("optimized", "naive")
     }
-    sets = {k: r.as_set() for k, r in results.items()}
-    assert sets["opt-sorted"] == sets["opt-hash"] == sets["naive"]
-    assert results["opt-sorted"].n_triples > 0
+    assert results["optimized"].as_set() == results["naive"].as_set()
+    assert results["optimized"].n_triples > 0
 
 
 @pytest.mark.parametrize("kind", ["SOM", "ORM", "OJM"])
@@ -48,6 +43,16 @@ def test_parse_from_file_and_run(tmp_path):
     assert n == res.n_triples
     lines = out.read_text().splitlines()
     assert all(line.endswith(" .") and line.count(" ") >= 3 for line in lines)
+
+
+def test_rdfize_rejects_the_join_flag(tmp_path, capsys):
+    """The sorted PJTT is the only join index: ``--join`` is no option."""
+    from repro.launch import rdfize
+
+    with pytest.raises(SystemExit) as exc:
+        rdfize.main(["--mapping", str(tmp_path / "map.ttl"), "--join", "hash"])
+    assert exc.value.code == 2
+    assert "unrecognized arguments: --join hash" in capsys.readouterr().err
 
 
 def test_generator_duplicate_rate():

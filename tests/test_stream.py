@@ -444,13 +444,6 @@ def test_stream_block_rows_invariance(tmp_path, block_rows):
     assert streamed == eager
 
 
-def test_stream_hash_join_strategy(tmp_path):
-    tb = generator.make_testbed("OJM", 800, 0.25, n_poms=1, seed=9)
-    tb.write(str(tmp_path))
-    assert _kg_lines(tb.doc, str(tmp_path), join_strategy="hash", stream=True,
-                     block_rows=128) == _kg_lines(tb.doc, str(tmp_path))
-
-
 def test_stream_never_uses_eager_loaders(tmp_path, monkeypatch):
     """Out-of-core guarantee: stream mode must go through the chunked
     readers only — the eager full-table loaders are never invoked."""

@@ -89,17 +89,44 @@ def test_null_typed_and_iri_safe_terms(cfg, data):
 
 
 def test_join_index_drops_null_keys_and_subjects():
-    """Child keys NULL or matching only NULL-subject parents join nothing,
-    in the hash PJTT too."""
+    """Child keys NULL or matching only NULL-subject parents join nothing."""
     doc = parser.parse(MAPPING)
     c = {k: np.array(v, dtype=object) for k, v in {
         "id": ["1", "2", "3"], "t": ["a", "b", "c"], "n": ["1", "1", "1"],
         "u": ["http://u", "http://u", "http://u"], "pk": ["", "k3", "k1"]}.items()}
     p = {"k": np.array(["", "k3", "k1"], dtype=object), "v": np.array(["z", "", "y"], dtype=object)}
-    for join in ("sorted", "hash"):
-        kg = create_kg(doc, tables={"csv:c.csv": c, "csv:p.csv": p}, join_strategy=join)
-        joined = [ln for ln in kg.iter_ntriples() if "<http://x/p>" in ln]
-        assert joined == ["<http://x/c/3-c> <http://x/p> <http://x/p/k1-y> ."]
+    kg = create_kg(doc, tables={"csv:c.csv": c, "csv:p.csv": p})
+    joined = [ln for ln in kg.iter_ntriples() if "<http://x/p>" in ln]
+    assert joined == ["<http://x/c/3-c> <http://x/p> <http://x/p/k1-y> ."]
+
+
+@pytest.mark.parametrize("stream", [False, True], ids=["eager", "stream"])
+def test_replay_counts_null_terms_once(stream, tmp_path):
+    """A PTT sized at 8 candidates a slot overflows and its predicates are
+    replayed; the rows they mask for a NULL term still count once."""
+    rows = ["id,t,n,u,pk"] + [
+        f"{i},{'' if i % 7 == 0 else i % 5},{'' if i % 3 == 0 else i},"
+        f"{'' if i % 4 == 0 else f'http://u/{i}'},{'' if i % 6 == 0 else f'k{i % 50}'}"
+        for i in range(600)
+    ]
+    (tmp_path / "c.csv").write_text("\n".join(rows) + "\n")
+    (tmp_path / "p.csv").write_text(
+        "k,v\n" + "".join(f"k{j},{'' if j % 9 == 0 else j}\n" for j in range(50))
+    )
+    doc = parser.parse(MAPPING)
+
+    def run(**cfg):
+        nulls, replays = _counter("ingest.null_terms"), _counter("ingest.replays")
+        kg = create_kg(doc, str(tmp_path), stream=stream, **cfg)
+        return (kg, _counter("ingest.null_terms") - nulls,
+                _counter("ingest.replays") - replays)
+
+    want, want_nulls, no_replays = run()
+    got, got_nulls, replays = run(load_factor=8.0)
+    assert no_replays == 0 and replays >= 1
+    assert want_nulls > 0 and got_nulls == want_nulls
+    assert got.stats == want.stats
+    assert got.sorted_ntriples() == want.sorted_ntriples()
 
 
 def test_datatype_and_term_type_roundtrip():
