@@ -23,13 +23,15 @@ only in operation count / wall-time, which is the paper's claim.
 Instrumentation (``repro.obs``), on the thread that runs the engine:
 spans ``ingest.read`` (source rows), ``ingest.encode`` (term columns to
 dictionary ids), ``ingest.pjtt_build``, ``ingest.size`` (the sizing
-pre-pass), ``ingest.step`` per batch with its children ``ingest.sync``
-(every blocking read of the step's outputs) and ``ingest.compact`` (the
-host-side selection of the emitted rows), and ``ingest.assemble``; the
+pre-pass, and the read-back of each PJTT's sorted keys), ``ingest.step``
+per batch with its children ``ingest.sync`` (every blocking read of the
+step's outputs) and ``ingest.compact`` (the host-side selection of the
+emitted rows), and ``ingest.assemble``; the
 counters ``ingest.host_syncs`` (blocking device-to-host reads),
 ``ingest.replays`` (predicates replayed after a PTT overflow), and
-``ingest.join_lanes`` and ``ingest.join_candidates`` (an OJM step's padded
-``rows × K`` probe lanes, and the valid ones among them), and
+``ingest.join_lanes`` and ``ingest.join_candidates`` (the lanes an OJM
+step hashes, its candidates rounded up by ``pjtt.lane_bucket``, and the
+candidates among them), and
 ``ingest.null_terms`` (source rows an op masks because a term it reads —
 subject, object or child join key — is NULL; counted once a predicate
 completes, so a replay does not count twice).  The N-Triples writer
@@ -42,6 +44,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -71,24 +74,19 @@ def _dedup_step(hi, lo, subj_tmpl, subj_vals, pred_id, obj_tmpl, obj_vals, valid
     return res.table.hi, res.table.lo, res.is_new, res.overflowed
 
 
-@partial(jax.jit, static_argnums=(8,), donate_argnums=(0, 1))
+@partial(jax.jit, donate_argnums=(0, 1))
 def _ojm_sorted_step(
-    hi, lo, skeys, ssubj, subj_tmpl, subj_vals, pred_id, obj_tmpl, max_matches,
-    child_keys, valid,
+    hi, lo, ssubj, subj_tmpl, subj_vals, pred_id, obj_tmpl, rows, pos, n_cand,
 ):
-    """OJM step, sorted PJTT: probe spans -> expand -> masked PTT insert."""
-    pr = pjtt.probe_sorted(pjtt.PJTTSorted(skeys, ssubj), child_keys, max_matches)
-    m, K = pr.subjects.shape
-    subj = jnp.broadcast_to(subj_vals[:, None], (m, K)).reshape(-1)
-    obj = pr.subjects.reshape(-1)
-    v = (pr.valid & valid[:, None]).reshape(-1)
-    khi, klo = hashing.triple_key(subj_tmpl, subj, pred_id, obj_tmpl, obj)
-    res = hashset.insert_masked(hashset.HashSet(hi, lo), khi, klo, v)
-    return (
-        res.table.hi, res.table.lo,
-        res.is_new.reshape(m, K), pr.subjects, v.reshape(m, K),
-        res.overflowed, pr.truncated,
-    )
+    """OJM step over a batch's ragged PJTT lanes (``pjtt.ragged_lanes``):
+    lane ``j < n_cand`` joins child row ``rows[j]`` to parent subject
+    ``ssubj[pos[j]]`` -> masked PTT insert.  Returns the parent subject of
+    every lane with ``is_new``."""
+    obj = ssubj[pos]
+    valid = jnp.arange(rows.shape[0], dtype=jnp.int32) < n_cand
+    khi, klo = hashing.triple_key(subj_tmpl, subj_vals[rows], pred_id, obj_tmpl, obj)
+    res = hashset.insert_masked(hashset.HashSet(hi, lo), khi, klo, valid)
+    return res.table.hi, res.table.lo, res.is_new, obj, res.overflowed
 
 
 @jax.jit
@@ -109,17 +107,27 @@ def _naive_dedup(khi, klo, valid):
 @jax.jit
 def _build_sorted(keys, subjects):
     """The PJTT of one parent: each distinct ``(key, subject)`` pair once,
-    so a probe's width is the largest number of distinct subjects of one
-    key, and ``skeys`` sizes the joins."""
+    so a key's span is its distinct subjects, and ``skeys`` sizes the
+    joins and lays out their lanes."""
     return pjtt.build_sorted(keys, subjects)
 
 
-@jax.jit
-def _span_stats(skeys, child_keys):
-    s = jnp.searchsorted(skeys, child_keys, side="left")
-    e = jnp.searchsorted(skeys, child_keys, side="right")
-    cnt = e - s
-    return jnp.sum(cnt), jnp.max(cnt)
+class _JoinIndex(NamedTuple):
+    """A parent's PJTT as the optimized engine's steps use it."""
+
+    skeys: np.ndarray   # host copy of the sorted keys: spans, sizing, lanes
+    ssubj: jax.Array    # the co-sorted subjects, on the device
+
+
+def _join_index(pkey: str, pkeys, psubj) -> _JoinIndex:
+    """Build a parent's PJTT on the device and read its sorted keys back
+    once (one host sync)."""
+    with obs.span("ingest.pjtt_build", pjtt=pkey):
+        idx = _build_sorted(jnp.asarray(pkeys), jnp.asarray(psubj))
+    with obs.span("ingest.size", pjtt=pkey):
+        skeys = np.asarray(idx.skeys)
+        obs.get_registry().inc("ingest.host_syncs")
+    return _JoinIndex(skeys, idx.ssubj)
 
 
 # --------------------------------------------------------------------------
@@ -323,7 +331,9 @@ class EngineConfig:
     engine: str = "optimized"        # optimized | naive
     batch_size: int = 1 << 16
     load_factor: float = 0.6
-    max_matches: int | None = None   # None -> derived from true max span
+    # the naive engine's nested-loop join width (None -> its widest raw
+    # span); the optimized engine hashes each join's candidates exactly
+    max_matches: int | None = None
     # streaming ingestion (repro.stream): block-at-a-time, out-of-core
     stream: bool = False
     block_rows: int = 1 << 14
@@ -404,24 +414,22 @@ class Engine:
             return value_cache[key]
 
         # ---- build PJTTs once per (parent map, join column), each with
-        # the sorted keys whose spans size its joins
+        # host-side sorted keys whose spans size its joins
         indexes: dict[str, tuple] = {}
-        span_keys: dict[str, jnp.ndarray] = {}
+        span_keys: dict[str, np.ndarray] = {}
         for pkey, (psrc, pcol, _ppat, pcols) in exec_plan.pjtt_builds.items():
             pkeys = values_for(psrc, (pcol,))
             psubj = values_for(psrc, pcols)
-            with obs.span("ingest.pjtt_build", pjtt=pkey):
-                kd = jnp.asarray(pkeys)
-                sd = jnp.asarray(psubj)
-                if cfg.engine == "naive":
-                    # raw arrays for the nested loop, which emits every raw
-                    # match: raw spans size it; a NULL key matches nothing
-                    kd = jnp.where(kd == NULL_ID, jnp.iinfo(jnp.int32).max, kd)
-                    indexes[pkey] = (kd, sd)
-                    span_keys[pkey] = jnp.sort(kd)
-                else:
-                    indexes[pkey] = _build_sorted(kd, sd)
-                    span_keys[pkey] = indexes[pkey].skeys
+            if cfg.engine == "naive":
+                # raw arrays for the nested loop, which emits every raw
+                # match: raw spans size it; a NULL key matches nothing
+                with obs.span("ingest.pjtt_build", pjtt=pkey):
+                    kd = np.where(pkeys == NULL_ID, np.iinfo(np.int32).max, pkeys)
+                    indexes[pkey] = (jnp.asarray(kd), jnp.asarray(psubj))
+                    span_keys[pkey] = np.sort(kd)
+            else:
+                indexes[pkey] = _join_index(pkey, pkeys, psubj)
+                span_keys[pkey] = indexes[pkey].skeys
 
         # ---- per-predicate candidate estimate -> PTT capacity
         stats: dict[str, PredicateStats] = {}
@@ -434,15 +442,12 @@ class Engine:
                 op = exec_plan.ops[i]
                 n_child = len(values_for(op.source_key, op.subj_columns))
                 if op.kind == "OJM":
-                    # exact |N_p| and max span from the index's sorted keys;
-                    # sizes the PTT and the padded-ragged probe width
+                    # exact |N_p| and max span from the index's sorted keys:
+                    # |N_p| sizes the PTT, the max span the naive join's width
                     ck = values_for(op.source_key, (op.join_child_column,))
                     with obs.span("ingest.size", op=i):
-                        tot, mx = _span_stats(
-                            span_keys[op.pjtt_key], jnp.asarray(ck)
-                        )
-                        tot, mx = int(tot), int(mx)
-                        obs.get_registry().inc("ingest.host_syncs", 2)
+                        _start, cnt = pjtt.spans(span_keys[op.pjtt_key], ck)
+                        tot, mx = int(cnt.sum()), int(cnt.max(initial=0))
                     op_spans[i] = (tot, mx)
                     total += tot
                 else:
@@ -483,7 +488,7 @@ class Engine:
                 if cfg.engine == "optimized":
                     self._run_predicates(
                         exec_plan, preds, op_input, indexes, pred_candidates,
-                        op_spans, stats, triples_out, dct,
+                        stats, triples_out, dct,
                     )
                 else:
                     self._run_naive(
@@ -501,10 +506,12 @@ class Engine:
 
     # -- the optimized engine: one predicate runner for both inputs -----------
 
-    def _step(self, op, spat, pid, opat, hi, lo, batch, index, K, out, st):
+    def _step(self, op, spat, pid, opat, hi, lo, batch, index, out, st):
         """Push one fixed-shape padded batch through the jitted step for
         ``op``; appends emitted triples to ``out`` and accumulates ``st``.
-        Returns ``(hi, lo, overflowed)``."""
+        An OJM batch hashes the exact ragged lanes of its rows' PJTT spans,
+        bucketed by ``pjtt.lane_bucket``; one with no candidate makes no
+        device call.  Returns ``(hi, lo, overflowed)``."""
         with obs.span("ingest.step", op=op.kind, rows=len(batch.valid)):
             # a lane whose subject, object or child key is NULL is no candidate
             other = batch.arrays["jkey" if op.kind == "OJM" else "obj"]
@@ -512,19 +519,22 @@ class Engine:
                 batch.valid & (batch.arrays["subj"] != NULL_ID) & (other != NULL_ID)
             )
             st.n_null += int(batch.valid.sum() - live.sum())
-            valid = jnp.asarray(live)
-            sv = jnp.asarray(batch.arrays["subj"])
+            sv = batch.arrays["subj"]
             if op.kind == "OJM":
-                ck = jnp.asarray(batch.arrays["jkey"])
-                hi, lo, is_new, psubj, v, ovf, trunc = _ojm_sorted_step(
-                    hi, lo, index.skeys, index.ssubj, spat, sv, pid,
-                    opat, K, ck, valid,
+                rows, pos, n_cand = pjtt.ragged_lanes(
+                    index.skeys, batch.arrays["jkey"], live
                 )
-                outputs = (trunc, is_new, v, psubj, ovf)
+                if n_cand == 0:
+                    return hi, lo, False
+                hi, lo, is_new, psubj, ovf = _ojm_sorted_step(
+                    hi, lo, index.ssubj, spat, jnp.asarray(sv), pid, opat,
+                    jnp.asarray(rows), jnp.asarray(pos), np.int32(n_cand),
+                )
+                outputs = (is_new, psubj, ovf)
             else:
                 ov = jnp.asarray(batch.arrays["obj"])
                 hi, lo, is_new, ovf = _dedup_step(
-                    hi, lo, spat, sv, pid, opat, ov, valid
+                    hi, lo, spat, jnp.asarray(sv), pid, opat, ov, jnp.asarray(live)
                 )
                 outputs = (is_new, ovf)
             reg = obs.get_registry()
@@ -533,25 +543,20 @@ class Engine:
                 reg.inc("ingest.host_syncs", len(got))
             with obs.span("ingest.compact"):
                 if op.kind == "OJM":
-                    trunc, is_new, v, psubj, ovf = got
-                    if trunc:
-                        raise RuntimeError(
-                            f"PJTT span exceeded max_matches={K}; "
-                            "re-run with a larger max_matches"
-                        )
-                    n_cand = int(v.sum())
+                    is_new, psubj, ovf = got
                     st.n_candidates += n_cand
-                    reg.inc("ingest.join_lanes", v.size)
+                    reg.inc("ingest.join_lanes", len(rows))
                     reg.inc("ingest.join_candidates", n_cand)
-                    rows, ks = np.nonzero(is_new & v)
-                    obj = psubj[rows, ks]
+                    lanes = np.nonzero(is_new)[0]  # a masked lane is never new
+                    rows = rows[lanes]
+                    obj = psubj[lanes]
                 else:
                     is_new, ovf = got
                     st.n_candidates += int(live.sum())
                     rows = np.nonzero(is_new & live)[0]
                     obj = batch.arrays["obj"][rows]
                 n_emit = len(rows)
-                out["subj_val"].append(batch.arrays["subj"][rows].astype(np.int32))
+                out["subj_val"].append(sv[rows].astype(np.int32))
                 out["obj_val"].append(obj.astype(np.int32))
                 out["subj_pat"].append(np.full(n_emit, spat, np.int32))
                 out["obj_pat"].append(np.full(n_emit, opat, np.int32))
@@ -559,8 +564,8 @@ class Engine:
         return hi, lo, bool(ovf)
 
     def _run_predicates(
-        self, exec_plan, preds, op_input, indexes, pred_candidates, op_spans,
-        stats, triples_out, dct: Dictionary,
+        self, exec_plan, preds, op_input, indexes, pred_candidates, stats,
+        triples_out, dct: Dictionary,
     ):
         """Run ``preds`` through the jitted steps, each into a PTT sized
         from its candidate count.  ``op_input(op)`` gives an op's
@@ -586,15 +591,14 @@ class Engine:
                     spat = np.int32(dct.encode_scalar(op.subj_pattern))
                     opat = np.int32(dct.encode_scalar(op.obj_pattern))
                     n_parent, n_child, batches = op_input(op)
-                    idx, K = None, 1
+                    idx = None
                     if op.kind == "OJM":
                         idx = indexes[op.pjtt_key]
-                        K = cfg.max_matches or max(int(op_spans[i][1]), 1)
                         st.n_parent += n_parent
                         st.n_child += n_child
                     for batch in batches:
                         hi, lo, overflow = self._step(
-                            op, spat, pid, opat, hi, lo, batch, idx, K, out, st
+                            op, spat, pid, opat, hi, lo, batch, idx, out, st
                         )
                         if overflow:
                             break
@@ -615,7 +619,7 @@ class Engine:
         Dataset; only dictionary-encoded int32 ids (and the PJTT indexes
         built from them) outlive a block, so host memory is bounded by
         O(block_rows) per raw column regardless of source size.  Sized like
-        the eager engine (exact span stats, streamed), with the same
+        the eager engine (exact span counts, streamed), with the same
         overflow-replay fallback — a replay re-reads the source rather than
         re-using a cached table.
 
@@ -851,9 +855,8 @@ class Engine:
                 yield Block(cols_out)
 
         # ---- PJTT builds: stream the parent once; retain only int32 ids
-        indexes: dict[str, tuple] = {}
+        indexes: dict[str, _JoinIndex] = {}
         parent_counts: dict[str, int] = {}
-        sorted_parent_keys: dict[str, np.ndarray] = {}
 
         def parent_columns(psrc, pcol, pcols):
             """The encoded join-key and subject columns of a parent."""
@@ -887,20 +890,13 @@ class Engine:
                 # the scan's blocks come out encoded: its reads count here
                 with obs.span("ingest.encode", source=psrc):
                     pkeys, psubj = parent_columns(psrc, pcol, pcols)
-                with obs.span("ingest.pjtt_build", pjtt=pkey):
-                    indexes[pkey] = _build_sorted(
-                        jnp.asarray(pkeys), jnp.asarray(psubj)
-                    )
-                with obs.span("ingest.size", pjtt=pkey):
-                    sorted_parent_keys[pkey] = np.asarray(indexes[pkey].skeys)
-                    reg.inc("ingest.host_syncs")
+                indexes[pkey] = _join_index(pkey, pkeys, psubj)
                 parent_counts[pkey] = len(pkeys)
                 row_counts[psrc] = len(pkeys)
 
-        # ---- sizing pre-pass: exact |N_p| and max span, streamed
+        # ---- sizing pre-pass: exact |N_p|, streamed
         stats: dict[str, PredicateStats] = {}
         pred_candidates: dict[str, int] = {}
-        op_spans: dict[int, tuple[int, int]] = {}
 
         def size_predicates(pred_list) -> None:
             for pred in pred_list:
@@ -914,19 +910,16 @@ class Engine:
             for i in op_idxs:
                 op = exec_plan.ops[i]
                 if op.kind == "OJM":
-                    spk = sorted_parent_keys[op.pjtt_key]
+                    spk = indexes[op.pjtt_key].skeys
                     ck_all = value_cache.get(
                         (op.source_key, (op.join_child_column,))
                     )
                     if ck_all is not None:
-                        # factored child key: span stats with no re-read
-                        cnt = np.searchsorted(spk, ck_all, side="right") \
-                            - np.searchsorted(spk, ck_all, side="left")
-                        tot = int(cnt.sum()) if len(cnt) else 0
-                        mx = int(cnt.max()) if len(cnt) else 0
+                        # factored child key: span counts with no re-read
+                        tot = int(pjtt.spans(spk, ck_all)[1].sum())
                         row_counts[op.source_key] = len(ck_all)
                     else:
-                        tot = mx = n = 0
+                        tot = n = 0
                         ds = (
                             dataset_for(op.source_key)
                             .project(
@@ -938,19 +931,12 @@ class Engine:
                         )
                         for block in _spanned("ingest.encode", ds.iter_blocks(prefetch)):
                             ck = block.columns[op.join_child_column]
-                            cnt = np.searchsorted(spk, ck, side="right") \
-                                - np.searchsorted(spk, ck, side="left")
-                            if len(cnt):
-                                tot += int(cnt.sum())
-                                mx = max(mx, int(cnt.max()))
+                            tot += int(pjtt.spans(spk, ck)[1].sum())
                             n += block.n_rows
                         row_counts[op.source_key] = n
-                    op_spans[i] = (tot, mx)
                     total += tot
                 else:
-                    n = count_rows(op.source_key)
-                    op_spans[i] = (n, 1)
-                    total += n
+                    total += count_rows(op.source_key)
             pred_candidates[pred] = total
 
         # ---- run the ops, block-at-a-time
@@ -977,8 +963,8 @@ class Engine:
 
         def run_predicates(preds) -> None:
             self._run_predicates(
-                exec_plan, preds, op_input, indexes, pred_candidates, op_spans,
-                stats, triples_out, dct,
+                exec_plan, preds, op_input, indexes, pred_candidates, stats,
+                triples_out, dct,
             )
 
         if mplan is None:
@@ -1002,7 +988,6 @@ class Engine:
                         del value_cache[key]
                 for pk in g.pjtt_keys:
                     indexes.pop(pk, None)
-                    sorted_parent_keys.pop(pk, None)
 
         stats = {pred: stats[pred] for pred in exec_plan.by_predicate}
         return KGResult(

@@ -10,13 +10,20 @@ the parent's ``(key, subject)`` pairs sorted once, and a probe is a pair of
 ``searchsorted`` calls yielding a ``[start, end)`` span — sequential access,
 one sort to build.
 
-A probe comes back in a *padded-ragged* layout: ``(m, max_matches)`` subject
-ids plus a validity mask — the TPU-native encoding of the N-M join output.
+The engine's OJM steps hash the *exact ragged* layout: the host finds each
+child row's span in a host copy of the sorted keys (:func:`spans`) and lays
+the batch's candidates out one lane each, row by row, in ``lane_bucket(T)``
+lanes for ``T`` candidates (:func:`ragged_lanes`).  :func:`probe_sorted`
+gives the *padded-ragged* layout, ``(m, max_matches)`` subject ids plus a
+validity mask; it remains for ``core/distributed.py``'s sharded probe and
+as the reference the ragged lanes are tested against.
+
 A span holds each distinct ``(key, subject)`` pair once, as the paper's PJTT
 maps a value to a *set* of subjects: repeated pairs are masked with a ``-1``
 subject and moved out of their key's span to a tail under the key
 ``INT32_MAX``, which is never a dictionary id.  So a key's span count is its
-number of distinct subjects, and that is the probe width it needs.
+number of distinct subjects, every lane of a span is a candidate, and the
+spans alone size and lay out a join.
 """
 
 from __future__ import annotations
@@ -26,6 +33,7 @@ from typing import NamedTuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.hashset import next_pow2
 from repro.data.encoder import NULL_ID
 
 _SUBJ_MASKED = np.int32(NULL_ID)  # a repeated pair, or a NULL subject
@@ -112,3 +120,56 @@ def _expand_spans(
     valid = within & (subjects != _SUBJ_MASKED)
     truncated = jnp.any(count > max_matches)
     return ProbeResult(subjects=subjects, valid=valid, truncated=truncated)
+
+
+# a ragged step's lanes are its candidates rounded up to a multiple of an
+# eighth of their next power of two, and of at least 1024: padding stays
+# under a quarter of the candidates (under 1024 lanes for a small step),
+# and the candidate counts of one doubling share at most four shapes
+_MIN_LANES = 1024
+_LANE_STEPS = 8
+
+
+def spans(skeys: np.ndarray, child_keys: np.ndarray) -> "tuple[np.ndarray, np.ndarray]":
+    """Each child key's ``[start, start + count)`` span in a host copy of
+    a PJTT's sorted keys: the key's distinct parent subjects.  The keys
+    are searched in sorted order, so the searches walk ``skeys`` forward
+    instead of jumping about it."""
+    order = np.argsort(child_keys)
+    needles = child_keys[order]
+    lo = np.searchsorted(skeys, needles, side="left")
+    hi = np.searchsorted(skeys, needles, side="right")
+    start = np.empty_like(lo)
+    count = np.empty_like(lo)
+    start[order] = lo
+    count[order] = hi - lo
+    return start, count
+
+
+def lane_bucket(n_cand: int) -> int:
+    """The lanes of a step over ``n_cand`` candidates: ``n_cand`` rounded
+    up to a multiple of ``max(1024, next_pow2(n_cand) // 8)``."""
+    step = max(_MIN_LANES, next_pow2(n_cand) // _LANE_STEPS)
+    return -(-n_cand // step) * step
+
+
+def ragged_lanes(
+    skeys: np.ndarray, child_keys: np.ndarray, live: np.ndarray
+) -> "tuple[np.ndarray, np.ndarray, int]":
+    """A batch's candidates one lane each: the ``live`` child rows' spans
+    in a host copy of a PJTT's sorted keys, in row-major ``(row, k)``
+    order.  Lane ``j`` below ``n_cand`` pairs child row ``rows[j]`` with
+    sorted position ``pos[j]``; the lanes after them, up to
+    ``lane_bucket(n_cand)``, point at row 0 and position 0.  Returns
+    ``(rows, pos, n_cand)``."""
+    start, count = spans(skeys, child_keys)
+    count = np.where(live, count, 0)
+    n_cand = int(count.sum())
+    n_lanes = lane_bucket(n_cand)
+    row = np.repeat(np.arange(len(count), dtype=np.int32), count)
+    first = np.cumsum(count) - count  # each row's first lane
+    rows = np.zeros(n_lanes, np.int32)
+    pos = np.zeros(n_lanes, np.int32)
+    rows[:n_cand] = row
+    pos[:n_cand] = start[row] - first[row] + np.arange(n_cand)
+    return rows, pos, n_cand

@@ -4,7 +4,7 @@ All-pairs equality join between child and parent join keys, shaped like a
 GEMM: a block of child keys stays resident in VMEM while parent tiles are
 streamed through the second grid dimension.  Matched parent subjects are
 packed left-to-right (parent order) into a padded (m, K) output — the same
-padded-ragged layout as the PJTT probe, so engine paths are interchangeable.
+padded-ragged layout as ``pjtt.probe_sorted``, so the two are interchangeable.
 
 Grid: (child_blocks, parent_tiles); parent tiles iterate innermost, so the
 output block and the per-row fill cursor act as sequential accumulators

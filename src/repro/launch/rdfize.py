@@ -48,7 +48,12 @@ def main(argv: list[str] | None = None) -> None:
     ap.add_argument("--mapping", required=True)
     ap.add_argument("--data-root", default=".")
     ap.add_argument("--out", default=None, help="N-Triples output path")
-    ap.add_argument("--engine", default="optimized", choices=("optimized", "naive"))
+    ap.add_argument("--engine", default="optimized", choices=("optimized", "naive"),
+                    help="optimized: PTT dedup and the PJTT index join, each "
+                         "join hashing exactly its candidates; naive: the "
+                         "nested-loop reference, its joins as wide as the "
+                         "widest span (EngineConfig.max_matches, which only "
+                         "the naive engine reads)")
     ap.add_argument("--batch-size", type=int, default=1 << 16)
     ap.add_argument("--stream", action="store_true",
                     help="block-streamed out-of-core ingestion (repro.stream)")

@@ -15,6 +15,7 @@ except ImportError:  # test image without hypothesis: seeded-example fallback
 from repro import obs
 from repro.core import executor, hashing, hashset, pjtt
 from repro.core.executor import create_kg
+from repro.data.encoder import NULL_ID
 from repro.rml import generator
 
 
@@ -171,11 +172,81 @@ def test_pjtt_raw_span_beyond_K_distinct_within():
     assert bool(_probe(pk, ps, [1, 2, 3], 2).truncated)
 
 
+# ------------------------------------------ the ragged lanes of the OJM step
+
+
+def _ragged(idx, ck, live):
+    """A batch's ragged lanes, laid out from the host copy of the keys."""
+    return pjtt.ragged_lanes(np.asarray(idx.skeys), ck, live)
+
+
+# parent rows, keys, subjects a key draws from, child rows, NULL child
+# keys' share, padding rows at the end; the last two cases have no
+# candidate at all (every key missing, every row padding)
+RAGGED_CASES = {
+    "repeated-pairs": (600, 40, 4, 300, 0.1, 37),
+    "fan-out-1": (500, 500, 1, 256, 0.0, 0),
+    "wide-spans": (900, 3, 60, 128, 0.2, 20),
+    "all-missing": (100, 10, 3, 64, 0.0, 0),
+    "all-padding": (100, 10, 3, 64, 0.0, 64),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RAGGED_CASES))
+def test_ragged_lanes_are_the_padded_probes_valid_lanes(case):
+    """On a PJTT with masked duplicate pairs and NULL parent keys, a batch
+    with empty spans, NULL child keys and padding rows: the ragged lanes
+    below the candidate count are the padded probe's valid ``(row,
+    subject)`` lanes of the live rows, in the same row-major order, and
+    the lanes are ``lane_bucket`` of that count."""
+    n, keys, subjects, m, null_share, pad = RAGGED_CASES[case]
+    rng = np.random.default_rng(n + m)
+    pk = rng.integers(0, keys, n).astype(np.int32)
+    ps = rng.integers(0, subjects, n).astype(np.int32) + 10 * pk
+    pk[rng.random(n) < 0.05] = NULL_ID
+    ck = rng.integers(0, keys + 5, m).astype(np.int32)
+    if case == "all-missing":
+        ck += keys
+    ck[rng.random(m) < null_share] = NULL_ID
+    live = ck != NULL_ID
+    live[m - pad:] = False
+    idx = pjtt.build_sorted(jnp.asarray(pk), jnp.asarray(ps))
+
+    rows, pos, n_cand = _ragged(idx, ck, live)
+    assert len(rows) == len(pos) == pjtt.lane_bucket(n_cand)
+    ssubj = np.asarray(idx.ssubj)
+    got = list(zip(rows[:n_cand].tolist(), ssubj[pos[:n_cand]].tolist()))
+
+    counts = pjtt.spans(np.asarray(idx.skeys), ck)[1]
+    pr = pjtt.probe_sorted(idx, jnp.asarray(ck), max(int(counts.max()), 1))
+    valid = np.asarray(pr.valid) & live[:, None]
+    r, k = np.nonzero(valid)
+    want = list(zip(r.tolist(), np.asarray(pr.subjects)[r, k].tolist()))
+    assert got == want
+    assert (n_cand == 0) == case.startswith("all-")
+
+
+def test_lane_bucket_pads_under_a_quarter():
+    """The lanes hold every candidate; padding is under a quarter of the
+    candidates, or under one 1024-lane block; and the counts of one
+    doubling share at most four lane shapes."""
+    assert pjtt.lane_bucket(0) == 0
+    counts = list(range(1, 1 << 15)) + [(1 << 20) + 1, 3 << 19, (1 << 21) - 1]
+    for t in counts:
+        lanes = pjtt.lane_bucket(t)
+        assert lanes >= t and lanes % 1024 == 0
+        assert lanes - t < max(t / 4, 1024)
+    for k in range(21):
+        doubling = range((1 << k) + 1, (2 << k) + 1, 1 + (k > 15) * 97)
+        assert len({pjtt.lane_bucket(t) for t in doubling}) <= 4
+
+
 def test_engines_agree_when_parents_repeat_pairs():
     """Parent rows repeat, so the raw widest span is wider than the
-    distinct one.  With max_matches left to the engine, the eager and
-    streamed engines (sized by distinct spans) and the naive engine (sized
-    by raw spans) give the same KG and none reports a truncated span."""
+    distinct one.  The eager and streamed engines (each join's distinct
+    candidates, laid out exactly) and the naive engine (its nested loop
+    as wide as the widest raw span) give the same KG, and the naive
+    engine reports no truncated span."""
     from repro.rml.plan import build_plan
 
     tb = generator.make_testbed("OJM", 800, 0.75, n_poms=2, seed=3)
@@ -241,13 +312,14 @@ def test_ojm_index_join_vs_nested_loop():
     K = int(np.bincount(pk).max()) + 1
 
     idx = executor._build_sorted(jnp.asarray(pk), jnp.asarray(psub))
+    rows, pos, n_cand = _ragged(idx, ck, np.ones(150, bool))
     table = hashset.make(hashset.next_pow2(200 * K))
-    _hi, _lo, is_new, _psubj, v, ovf, trunc = executor._ojm_sorted_step(
-        table.hi, table.lo, idx.skeys, idx.ssubj, SPAT, jnp.asarray(csub), PID,
-        OPAT, K, jnp.asarray(ck), jnp.ones(150, bool),
+    _hi, _lo, is_new, _psubj, ovf = executor._ojm_sorted_step(
+        table.hi, table.lo, idx.ssubj, SPAT, jnp.asarray(csub), PID, OPAT,
+        jnp.asarray(rows), jnp.asarray(pos), np.int32(n_cand),
     )
-    assert not bool(ovf) and not bool(trunc)
-    n_opt = int(np.asarray(is_new & v).sum())
+    assert not bool(ovf)
+    n_opt = int(np.asarray(is_new).sum())
 
     jr = executor._naive_join_step(jnp.asarray(pk), jnp.asarray(psub), K, jnp.asarray(ck))
     assert not bool(jr.truncated)
@@ -262,6 +334,50 @@ def test_ojm_index_join_vs_nested_loop():
         for ps_ in d.get(k, ()):
             pairs.add((s, ps_))
     assert n_opt == len(pairs)
+
+
+def _padded_step(hi, lo, idx, subj, ck, live, K):
+    """The OJM step on the padded ``(rows, K)`` probe block: every lane
+    hashed, the invalid ones masked."""
+    pr = pjtt.probe_sorted(idx, jnp.asarray(ck), K)
+    v = (np.asarray(pr.valid) & live[:, None]).reshape(-1)
+    khi, klo = hashing.triple_key(
+        SPAT, jnp.asarray(np.repeat(subj, K)), PID, OPAT, pr.subjects.reshape(-1)
+    )
+    res = hashset.insert_masked(hashset.HashSet(hi, lo), khi, klo, jnp.asarray(v))
+    r, k = np.nonzero(np.asarray(res.is_new).reshape(-1, K))
+    emitted = list(zip(r.tolist(), np.asarray(pr.subjects)[r, k].tolist()))
+    return res.table.hi, res.table.lo, emitted
+
+
+@pytest.mark.parametrize("slots", [1 << 11, 1 << 14])
+def test_ojm_step_emits_what_the_padded_step_emits(slots):
+    """Batch after batch into one PTT, small enough that keys contend for
+    slots: the ragged step marks new the same ``(row, subject)`` pairs as
+    the padded step, in the same order, and leaves the same table."""
+    rng = np.random.default_rng(slots)
+    pk = rng.integers(0, 30, 400).astype(np.int32)
+    ps = rng.integers(0, 6, 400).astype(np.int32) + 10 * pk
+    idx = executor._build_sorted(jnp.asarray(pk), jnp.asarray(ps))
+    K = max(int(pjtt.spans(np.asarray(idx.skeys), np.arange(30))[1].max()), 1)
+    ragged, padded = hashset.make(slots), hashset.make(slots)
+    rhi, rlo, phi, plo = ragged.hi, ragged.lo, padded.hi, padded.lo
+    for _batch in range(3):
+        ck = rng.integers(0, 33, 64).astype(np.int32)
+        subj = rng.integers(0, 40, 64).astype(np.int32)
+        live = rng.random(64) < 0.9
+        rows, pos, n_cand = _ragged(idx, ck, live)
+        rhi, rlo, is_new, psubj, ovf = executor._ojm_sorted_step(
+            rhi, rlo, idx.ssubj, SPAT, jnp.asarray(subj), PID, OPAT,
+            jnp.asarray(rows), jnp.asarray(pos), np.int32(n_cand),
+        )
+        assert not bool(ovf)
+        lanes = np.nonzero(np.asarray(is_new))[0]
+        got = list(zip(rows[lanes].tolist(), np.asarray(psubj)[lanes].tolist()))
+        phi, plo, want = _padded_step(phi, plo, idx, subj, ck, live, K)
+        assert got == want and len(got) > 0
+    assert np.array_equal(np.asarray(rhi), np.asarray(phi))
+    assert np.array_equal(np.asarray(rlo), np.asarray(plo))
 
 
 # ------------------------------------------------- the shared predicate runner

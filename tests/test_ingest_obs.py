@@ -8,15 +8,17 @@ import collections
 import pytest
 
 from repro import obs
+from repro.core import pjtt
 from repro.core.executor import create_kg
 from repro.data import pipeline
 from repro.rml import generator
 from repro.rml.plan import build_plan
 
-# blocking device-to-host reads per batch: OJM reads trunc, is_new, v,
-# psubj and ovf; a dedup step reads is_new and ovf
-READS_PER_BATCH = {"OJM": 5}
-SIZING_READS = 2  # the eager engine's int() of |N_p| and the max span
+# blocking device-to-host reads per batch: an OJM batch with candidates
+# reads is_new, the lanes' parent subjects and ovf (one without makes no
+# device call); a dedup step reads is_new and ovf.  Both engines read
+# each PJTT's sorted keys back once.
+READS_PER_BATCH = {"OJM": 3}
 COUNTERS = ("ingest.host_syncs", "ingest.replays", "ingest.join_lanes",
             "ingest.join_candidates")
 
@@ -29,44 +31,56 @@ def _tables(tb):
 
 
 def _batches(n, batch_size, stream, block_rows):
-    """The number of device batches of an ``n``-row op, and the rows each
-    is padded to."""
+    """The ``(start, end)`` source rows of an ``n``-row op's device
+    batches."""
     if stream:
         rows = min(batch_size, block_rows)
-        blocks = [min(block_rows, n - s) for s in range(0, n, block_rows)]
-        return sum(-(-b // rows) for b in blocks), rows
+        return [(a, min(a + rows, b0 + block_rows, n))
+                for b0 in range(0, n, block_rows)
+                for a in range(b0, min(b0 + block_rows, n), rows)]
     bs = min(batch_size, pipeline.pick_batch_size(n))
-    return -(-n // bs), bs
+    return [(a, min(a + bs, n)) for a in range(0, n, bs)]
+
+
+def _candidates(plan, op, tables):
+    """Each child row's candidates: the distinct parent subjects of its
+    join key."""
+    psrc, pcol, _ppat, pcols = plan.pjtt_builds[op.pjtt_key]
+    subjects = collections.defaultdict(set)
+    for key, *subj in zip(tables[psrc][pcol], *(tables[psrc][c] for c in pcols)):
+        subjects[key].add(tuple(subj))
+    return [len(subjects[k]) for k in tables[op.source_key][op.join_child_column]]
+
+
+def _op_batches(doc, tables, batch_size, stream, block_rows):
+    """Each op and its device batches' candidate counts (the rows of a
+    batch for an op that joins nothing)."""
+    plan = build_plan(doc).exec_plan
+    for op in plan.ops:
+        if op.kind == "OJM":
+            counts = _candidates(plan, op, tables)
+        else:
+            counts = [1] * len(next(iter(tables[op.source_key].values())))
+        yield op, [sum(counts[a:b]) for a, b in
+                   _batches(len(counts), batch_size, stream, block_rows)]
 
 
 def expected_host_syncs(doc, tables, batch_size, stream=False, block_rows=None):
-    plan = build_plan(doc).exec_plan
-    # the streamed engine reads each PJTT's sorted keys back for its sizing
-    total = len(plan.pjtt_builds) if stream else 0
-    for op in plan.ops:
-        n = len(next(iter(tables[op.source_key].values())))
-        n_batches, _rows = _batches(n, batch_size, stream, block_rows)
-        total += READS_PER_BATCH.get(op.kind, 2) * n_batches
-        if op.kind == "OJM" and not stream:
-            total += SIZING_READS
+    total = len(build_plan(doc).exec_plan.pjtt_builds)
+    for op, steps in _op_batches(doc, tables, batch_size, stream, block_rows):
+        total += READS_PER_BATCH.get(op.kind, 2) * sum(1 for t in steps if t)
     return total
 
 
 def expected_join_lanes(doc, tables, batch_size, stream=False, block_rows=None):
-    """The OJM steps' probe lanes and the valid ones among them: each op's
-    padded rows × K, K the most distinct parent subjects of a child key,
-    and the child rows' distinct-subject counts."""
-    plan = build_plan(doc).exec_plan
+    """The lanes the OJM steps hash and the candidates among them: each
+    batch's candidates, the child rows' distinct parent subjects, rounded
+    up by ``pjtt.lane_bucket``."""
     lanes = candidates = 0
-    for op in (op for op in plan.ops if op.kind == "OJM"):
-        psrc, pcol, _ppat, pcols = plan.pjtt_builds[op.pjtt_key]
-        subjects = collections.defaultdict(set)
-        for key, *subj in zip(tables[psrc][pcol], *(tables[psrc][c] for c in pcols)):
-            subjects[key].add(tuple(subj))
-        counts = [len(subjects[k]) for k in tables[op.source_key][op.join_child_column]]
-        n_batches, rows = _batches(len(counts), batch_size, stream, block_rows)
-        lanes += n_batches * rows * max(max(counts), 1)
-        candidates += sum(counts)
+    for op, steps in _op_batches(doc, tables, batch_size, stream, block_rows):
+        if op.kind == "OJM":
+            lanes += sum(pjtt.lane_bucket(t) for t in steps)
+            candidates += sum(steps)
     return lanes, candidates
 
 
@@ -102,8 +116,10 @@ def test_host_syncs_count_the_batches(kind, engine):
 @pytest.mark.parametrize("engine", sorted(ENGINES))
 @pytest.mark.parametrize("kind", ["OJM", "SOM"])
 def test_join_lanes_count_the_distinct_spans(kind, engine):
-    """An OJM step probes rows × K lanes, K the widest distinct span: the
-    parent's repeated rows widen no span.  A SOM job joins nothing."""
+    """An OJM step hashes its batch's candidates, rounded up by
+    ``pjtt.lane_bucket``: a child row gives one lane for each distinct
+    parent subject of its key, so the parent's repeated rows add none.  A
+    SOM job joins nothing."""
     tb = generator.make_testbed(kind, 1500, 0.75, n_poms=2, seed=5)
     tables = _tables(tb)
     config = ENGINES[engine]

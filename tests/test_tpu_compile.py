@@ -23,6 +23,7 @@ from repro.serve.exec import get_executor
 BATCH = 1 << 16            # the engine's default step batch
 PTT_SLOTS = 1 << 22        # the PTT of a ~2.5M-candidate predicate
 PJTT_KEYS = 1 << 20        # the PJTT of a 1M-row parent source
+JOIN_LANES = 81920         # a full batch's lanes at 1.125 candidates a row
 SORT_ROWS = 4 << 20        # a 4M-triple store's index sort
 STORE_N = 1_340_000        # the chip smoke test's OJM KG
 STORE_TERMS = 790_000
@@ -63,12 +64,12 @@ def test_dedup_step_compiles(one_chip):
 
 def test_ojm_sorted_step_compiles(one_chip):
     u32 = spec((PTT_SLOTS,), jnp.uint32, one_chip)
-    keys = spec((PJTT_KEYS,), jnp.int32, one_chip)
+    ssubj = spec((PJTT_KEYS,), jnp.int32, one_chip)
     i32 = spec((), jnp.int32, one_chip)
     col = spec((BATCH,), jnp.int32, one_chip)
-    valid = spec((BATCH,), jnp.bool_, one_chip)
+    lanes = spec((JOIN_LANES,), jnp.int32, one_chip)
     E._ojm_sorted_step.lower(
-        u32, u32, keys, keys, i32, col, i32, i32, 8, col, valid
+        u32, u32, ssubj, i32, col, i32, i32, lanes, lanes, i32
     ).compile()
 
 
