@@ -34,13 +34,20 @@ step hashes, its candidates rounded up by ``pjtt.lane_bucket``, and the
 candidates among them), and
 ``ingest.null_terms`` (source rows an op masks because a term it reads —
 subject, object or child join key — is NULL; counted once a predicate
-completes, so a replay does not count twice).  The N-Triples writer
+completes, so a replay does not count twice).  A join on two or more
+conditions keys each row by the joined values of its columns, encoded in
+the same dictionary as a multi-column template value (a NULL part makes
+the key NULL): span ``ingest.join_key`` (args ``columns``) times each such
+build, nested in the open ``ingest.*`` span, and the counter
+``ingest.join_key_rows`` counts the child rows of each such join and the
+parent rows of each such PJTT, once a job.  The N-Triples writer
 counts ``emit.lines`` (lines written) and ``emit.terms_rendered`` (the
 distinct terms it rendered, each once).
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from functools import partial
@@ -316,9 +323,23 @@ def _touch_counters() -> None:
     replay reads 0 rather than nothing."""
     reg = obs.get_registry()
     for name in ("ingest.host_syncs", "ingest.replays", "ingest.join_lanes",
-                 "ingest.join_candidates", "ingest.null_terms", "emit.lines",
-                 "emit.terms_rendered"):
+                 "ingest.join_candidates", "ingest.null_terms", "ingest.join_key_rows",
+                 "emit.lines", "emit.terms_rendered"):
         reg.counter(name)
+
+
+def _join_key(columns: tuple[str, ...]):
+    """Span ``ingest.join_key`` around building a join key from two or
+    more columns (their joined values and its encode); a one-column key
+    opens none."""
+    if len(columns) < 2:
+        return contextlib.nullcontext()
+    return obs.span("ingest.join_key", columns=",".join(columns))
+
+
+def _count_join_key_rows(columns: tuple[str, ...], n: int) -> None:
+    if len(columns) > 1:
+        obs.get_registry().inc("ingest.join_key_rows", n)
 
 
 # --------------------------------------------------------------------------
@@ -405,20 +426,24 @@ class Engine:
         # ---- encode the value columns each op needs (once per column set)
         value_cache: dict[tuple, np.ndarray] = {}
 
-        def values_for(source_key: str, columns: tuple[str, ...]) -> np.ndarray:
+        def values_for(
+            source_key: str, columns: tuple[str, ...], join_key: bool = False
+        ) -> np.ndarray:
             key = (source_key, columns)
             if key not in value_cache:
                 table = get_table(source_key)
-                with obs.span("ingest.encode", source=source_key):
+                with obs.span("ingest.encode", source=source_key), \
+                        _join_key(columns if join_key else ()):
                     value_cache[key] = self._term_values(dct, table, columns)
             return value_cache[key]
 
-        # ---- build PJTTs once per (parent map, join column), each with
+        # ---- build PJTTs once per (parent map, join columns), each with
         # host-side sorted keys whose spans size its joins
         indexes: dict[str, tuple] = {}
         span_keys: dict[str, np.ndarray] = {}
-        for pkey, (psrc, pcol, _ppat, pcols) in exec_plan.pjtt_builds.items():
-            pkeys = values_for(psrc, (pcol,))
+        for pkey, (psrc, pjcols, _ppat, pcols) in exec_plan.pjtt_builds.items():
+            pkeys = values_for(psrc, pjcols, join_key=True)
+            _count_join_key_rows(pjcols, len(pkeys))
             psubj = values_for(psrc, pcols)
             if cfg.engine == "naive":
                 # raw arrays for the nested loop, which emits every raw
@@ -444,7 +469,8 @@ class Engine:
                 if op.kind == "OJM":
                     # exact |N_p| and max span from the index's sorted keys:
                     # |N_p| sizes the PTT, the max span the naive join's width
-                    ck = values_for(op.source_key, (op.join_child_column,))
+                    ck = values_for(op.source_key, op.join_child_columns, join_key=True)
+                    _count_join_key_rows(op.join_child_columns, len(ck))
                     with obs.span("ingest.size", op=i):
                         _start, cnt = pjtt.spans(span_keys[op.pjtt_key], ck)
                         tot, mx = int(cnt.sum()), int(cnt.max(initial=0))
@@ -462,10 +488,8 @@ class Engine:
             cols = {"subj": subj_vals}
             n_parent = 0
             if op.kind == "OJM":
-                cols["jkey"] = values_for(op.source_key, (op.join_child_column,))
-                n_parent = len(
-                    values_for(op.parent_source_key, (op.parent_join_column,))
-                )
+                cols["jkey"] = values_for(op.source_key, op.join_child_columns, join_key=True)
+                n_parent = len(values_for(op.parent_source_key, op.parent_join_columns))
             elif op.kind in ("SOM", "ORM"):
                 cols["obj"] = values_for(op.source_key, op.obj_columns)
             else:  # CLASS: constant object
@@ -689,9 +713,24 @@ class Engine:
                 return np.zeros(block.n_rows, dtype=np.int32)
             return join_columns([block.columns[c] for c in columns])
 
+        def key_column(block: Block, columns: tuple) -> np.ndarray:
+            """A join key for a pipeline: one column as read (the
+            pipeline's Encode gives it ids), or two or more joined and
+            encoded here, under ``ingest.join_key``."""
+            if len(columns) == 1:
+                return block.columns[columns[0]]
+            with _join_key(columns):
+                return dct.encode(derived(block, columns))
+
+        def key_prefetch(columns: tuple) -> int:
+            """A pipeline that builds a key from two or more columns runs
+            on the engine's thread, so that its ``ingest.join_key`` spans
+            nest in the engine's open span."""
+            return prefetch if len(columns) < 2 else 0
+
         def op_dataset(op) -> Dataset:
             if op.kind == "OJM":
-                extra: tuple = (op.join_child_column,)
+                extra: tuple = op.join_child_columns
             elif op.kind in ("SOM", "ORM"):
                 extra = tuple(op.obj_columns)
             else:
@@ -701,7 +740,7 @@ class Engine:
             def to_term_columns(block: Block) -> Block:
                 cols = {"subj": derived(block, op.subj_columns)}
                 if op.kind == "OJM":
-                    cols["jkey"] = block.columns[op.join_child_column]
+                    cols["jkey"] = key_column(block, op.join_child_columns)
                 elif op.kind in ("SOM", "ORM"):
                     cols["obj"] = derived(block, op.obj_columns)
                 else:  # CLASS: constant object
@@ -726,12 +765,12 @@ class Engine:
             cols = refcols.setdefault(op.source_key, set())
             cols.update(op.subj_columns)
             if op.kind == "OJM":
-                cols.add(op.join_child_column)
+                cols.update(op.join_child_columns)
             else:
                 cols.update(op.obj_columns)
-        for psrc_, pcol_, _ppat_, pcols_ in exec_plan.pjtt_builds.values():
+        for psrc_, pjcols_, _ppat_, pcols_ in exec_plan.pjtt_builds.values():
             cols = refcols.setdefault(psrc_, set())
-            cols.add(pcol_)
+            cols.update(pjcols_)
             cols.update(pcols_)
         row_counts: dict[str, int] = {}
         for skey, cols in refcols.items():
@@ -755,6 +794,12 @@ class Engine:
         # the dictionary-encoded int32 value column of a term evaluated by
         # >= 2 sites.  Filled per group, freed when the group completes.
         value_cache: dict[tuple, np.ndarray] = {}
+        # the (source, columns) that some join keys on: two or more columns
+        # factored for it are built under ingest.join_key
+        join_keys = {(op.source_key, op.join_child_columns)
+                     for op in exec_plan.ops if op.kind == "OJM"}
+        join_keys.update((psrc_, pjcols_)
+                         for psrc_, pjcols_, _p, _c in exec_plan.pjtt_builds.values())
 
         def build_factored(group) -> None:
             """One streaming pass per source with shared terms: evaluate
@@ -776,7 +821,8 @@ class Engine:
                 with obs.span("ingest.encode", source=skey):
                     for block in _spanned("ingest.read", ds.iter_blocks(prefetch)):
                         for cols in colsets:
-                            chunks[cols].append(dct.encode(derived(block, cols)))
+                            with _join_key(cols if (skey, cols) in join_keys else ()):
+                                chunks[cols].append(dct.encode(derived(block, cols)))
                         n += block.n_rows
                     for cols in colsets:
                         value_cache[(skey, cols)] = (
@@ -799,7 +845,7 @@ class Engine:
             stream the source with the projection pushed into the read."""
             slots: list[tuple[str, tuple]] = [("subj", tuple(op.subj_columns))]
             if op.kind == "OJM":
-                slots.append(("jkey", (op.join_child_column,)))
+                slots.append(("jkey", op.join_child_columns))
             elif op.kind in ("SOM", "ORM"):
                 slots.append(("obj", tuple(op.obj_columns)))
             else:  # CLASS: constant object
@@ -848,7 +894,8 @@ class Engine:
                         elif not cols:
                             cols_out[name] = np.zeros(m, np.int32)
                         else:
-                            cols_out[name] = dct.encode(derived(block, cols))
+                            with _join_key(cols if name == "jkey" else ()):
+                                cols_out[name] = dct.encode(derived(block, cols))
                 if cached:
                     reg.inc("plan.factored_rows", m * len(cached))
                 offset += m
@@ -858,19 +905,19 @@ class Engine:
         indexes: dict[str, _JoinIndex] = {}
         parent_counts: dict[str, int] = {}
 
-        def parent_columns(psrc, pcol, pcols):
+        def parent_columns(psrc, pjcols, pcols):
             """The encoded join-key and subject columns of a parent."""
-            kc = value_cache.get((psrc, (pcol,)))
+            kc = value_cache.get((psrc, pjcols))
             sc = value_cache.get((psrc, tuple(pcols)))
             if kc is not None and (sc is not None or not pcols):
                 # both columns already factored: build from the cache
                 reg.inc("plan.factored_rows", 2 * len(kc))
                 return kc, sc if sc is not None else np.zeros(len(kc), np.int32)
-            needed = tuple(dict.fromkeys((pcol,) + tuple(pcols)))
+            needed = tuple(dict.fromkeys(pjcols + tuple(pcols)))
 
             def to_index_columns(block: Block) -> Block:
                 return Block(
-                    {"key": block.columns[pcol], "subj": derived(block, pcols)}
+                    {"key": key_column(block, pjcols), "subj": derived(block, pcols)}
                 )
 
             ds = dataset_for(psrc).project(
@@ -878,7 +925,7 @@ class Engine:
             )
             ds = ds.map_blocks(to_index_columns).encode(dct)
             kchunks, schunks = [], []
-            for block in ds.iter_blocks(prefetch):
+            for block in ds.iter_blocks(key_prefetch(pjcols)):
                 kchunks.append(block.columns["key"])
                 schunks.append(block.columns["subj"])
             if not kchunks:
@@ -886,10 +933,11 @@ class Engine:
             return np.concatenate(kchunks), np.concatenate(schunks)
 
         def build_pjtts(pjtt_items) -> None:
-            for pkey, (psrc, pcol, _ppat, pcols) in pjtt_items:
+            for pkey, (psrc, pjcols, _ppat, pcols) in pjtt_items:
                 # the scan's blocks come out encoded: its reads count here
                 with obs.span("ingest.encode", source=psrc):
-                    pkeys, psubj = parent_columns(psrc, pcol, pcols)
+                    pkeys, psubj = parent_columns(psrc, pjcols, pcols)
+                _count_join_key_rows(pjcols, len(pkeys))
                 indexes[pkey] = _join_index(pkey, pkeys, psubj)
                 parent_counts[pkey] = len(pkeys)
                 row_counts[psrc] = len(pkeys)
@@ -911,9 +959,8 @@ class Engine:
                 op = exec_plan.ops[i]
                 if op.kind == "OJM":
                     spk = indexes[op.pjtt_key].skeys
-                    ck_all = value_cache.get(
-                        (op.source_key, (op.join_child_column,))
-                    )
+                    jcols = op.join_child_columns
+                    ck_all = value_cache.get((op.source_key, jcols))
                     if ck_all is not None:
                         # factored child key: span counts with no re-read
                         tot = int(pjtt.spans(spk, ck_all)[1].sum())
@@ -923,17 +970,20 @@ class Engine:
                         ds = (
                             dataset_for(op.source_key)
                             .project(
-                                op.join_child_column,
+                                *jcols,
                                 fill=fill_of(op.source_key),
                                 pushdown=mplan is not None,
                             )
+                            .map_blocks(lambda b: Block({"key": key_column(b, jcols)}))
                             .encode(dct)
                         )
-                        for block in _spanned("ingest.encode", ds.iter_blocks(prefetch)):
-                            ck = block.columns[op.join_child_column]
+                        blocks = ds.iter_blocks(key_prefetch(jcols))
+                        for block in _spanned("ingest.encode", blocks):
+                            ck = block.columns["key"]
                             tot += int(pjtt.spans(spk, ck)[1].sum())
                             n += block.n_rows
                         row_counts[op.source_key] = n
+                    _count_join_key_rows(jcols, row_counts[op.source_key])
                     total += tot
                 else:
                     total += count_rows(op.source_key)
@@ -950,7 +1000,8 @@ class Engine:
                 op_blocks(op)
                 if mplan is not None
                 # the unplanned stream encodes as it reads
-                else _spanned("ingest.encode", op_dataset(op).iter_blocks(prefetch))
+                else _spanned("ingest.encode", op_dataset(op).iter_blocks(
+                    key_prefetch(op.join_child_columns)))
             )
             batches = (
                 batch
@@ -1021,7 +1072,7 @@ class Engine:
                     pkeys, psubj = indexes[op.pjtt_key]
                     tot, mx = op_spans[i]
                     K = cfg.max_matches or max(int(mx), 1)
-                    ck = jnp.asarray(values_for(op.source_key, (op.join_child_column,)))
+                    ck = jnp.asarray(values_for(op.source_key, op.join_child_columns))
                     jr = _naive_join_step(pkeys, psubj, K, ck)
                     if bool(jr.truncated):
                         raise RuntimeError("naive join exceeded max_matches")

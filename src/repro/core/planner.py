@@ -50,12 +50,14 @@ class PlannedOp:
     subj_columns: tuple[str, ...]
     obj_pattern: str
     obj_columns: tuple[str, ...]          # SOM: source cols; ORM: parent subj cols
-    join_child_column: str | None = None  # OJM only
+    # OJM only: the join conditions' child columns in order (the child key
+    # is their joined values); parent_join_columns holds their parent columns
+    join_child_columns: tuple[str, ...] = ()
     pjtt_key: str | None = None           # OJM only: cache key of the index
     parent_source_key: str | None = None
     parent_subj_pattern: str | None = None
     parent_subj_columns: tuple[str, ...] = ()
-    parent_join_column: str | None = None
+    parent_join_columns: tuple[str, ...] = ()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,8 +65,8 @@ class ExecutionPlan:
     ops: tuple[PlannedOp, ...]
     # predicate -> ops generating it (PTT sizing + shared-table bookkeeping)
     by_predicate: dict[str, tuple[int, ...]]
-    # pjtt_key -> (parent_source_key, parent_join_column, parent_subj_*)
-    pjtt_builds: dict[str, tuple[str, str, str, tuple[str, ...]]]
+    # pjtt_key -> (parent_source_key, parent_join_columns, parent_subj_*)
+    pjtt_builds: dict[str, tuple[str, tuple[str, ...], str, tuple[str, ...]]]
 
 
 def _src_key(tm: TriplesMap) -> str:
@@ -73,7 +75,7 @@ def _src_key(tm: TriplesMap) -> str:
 
 def plan(doc: MappingDocument) -> ExecutionPlan:
     ops: list[PlannedOp] = []
-    pjtt_builds: dict[str, tuple[str, str, str, tuple[str, ...]]] = {}
+    pjtt_builds: dict[str, tuple[str, tuple[str, ...], str, tuple[str, ...]]] = {}
 
     for tm in doc.triples_maps.values():
         subj_pat = term_pattern(tm.subject)
@@ -124,14 +126,16 @@ def plan(doc: MappingDocument) -> ExecutionPlan:
                     )
                 )
             else:  # OJM
-                assert isinstance(om, RefObjectMap) and om.join is not None
+                assert isinstance(om, RefObjectMap) and om.joins
                 parent = doc.triples_maps[om.parent_triples_map]
-                pkey = f"{parent.name}\x1f{om.join.parent}"
+                pcols = tuple(jc.parent for jc in om.joins)
+                # one index per (parent map, join columns): "name\x1fcol..."
+                pkey = "\x1f".join((parent.name,) + pcols)
                 pjtt_builds.setdefault(
                     pkey,
                     (
                         _src_key(parent),
-                        om.join.parent,
+                        pcols,
                         term_pattern(parent.subject),
                         parent.subject.columns,
                     ),
@@ -146,12 +150,12 @@ def plan(doc: MappingDocument) -> ExecutionPlan:
                         subj_columns=subj_cols,
                         obj_pattern=term_pattern(parent.subject),
                         obj_columns=parent.subject.columns,
-                        join_child_column=om.join.child,
+                        join_child_columns=tuple(jc.child for jc in om.joins),
                         pjtt_key=pkey,
                         parent_source_key=_src_key(parent),
                         parent_subj_pattern=term_pattern(parent.subject),
                         parent_subj_columns=parent.subject.columns,
-                        parent_join_column=om.join.parent,
+                        parent_join_columns=pcols,
                     )
                 )
 

@@ -180,7 +180,7 @@ def make_orm_testbed(
         poms.append(
             PredicateObjectMap(
                 predicate=f"{BASE}vocab/has_{col.lower()}",
-                object_map=RefObjectMap(parent_triples_map=pname, join=None),
+                object_map=RefObjectMap(parent_triples_map=pname),
             )
         )
     maps["TriplesMap1"] = TriplesMap(
@@ -227,9 +227,9 @@ def make_ojm_testbed(
                 predicate=f"{BASE}vocab/in_exon_{i+1}",
                 object_map=RefObjectMap(
                     parent_triples_map=pname,
-                    join=JoinCondition(
+                    joins=(JoinCondition(
                         child="ACCESSION_NUMBER", parent="ACCESSION_NUMBER"
-                    ),
+                    ),),
                 ),
             )
         )

@@ -7,7 +7,7 @@ exactly one physical operator:
 
 * plain object map                        -> SOM
 * parentTriplesMap, same logical source   -> ORM
-* parentTriplesMap + joinCondition        -> OJM
+* parentTriplesMap + joinCondition(s)     -> OJM
 """
 
 from __future__ import annotations
@@ -131,8 +131,12 @@ class JoinCondition:
 
 @dataclasses.dataclass(frozen=True)
 class RefObjectMap:
+    """No join condition -> ORM (same source); one or more -> OJM, where a
+    child row joins every parent row whose ``parent`` columns all equal its
+    ``child`` columns (a composite foreign key has a condition a column)."""
+
     parent_triples_map: str
-    join: JoinCondition | None = None  # None -> ORM (same source), else OJM
+    joins: tuple[JoinCondition, ...] = ()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -164,7 +168,7 @@ class MappingDocument:
         if isinstance(om, TermMap):
             return "SOM"
         parent = self.triples_maps[om.parent_triples_map]
-        if om.join is None:
+        if not om.joins:
             if parent.source != tm.source:
                 raise ValueError(
                     f"ORM {tm.name}->{parent.name} requires a shared logical source"

@@ -4,17 +4,18 @@ Supports the constructs exercised in the paper's Figure 1 / testbeds:
 ``@prefix``, triples maps with ``rml:logicalSource``, ``rr:subjectMap``
 (template + class), ``rr:predicateObjectMap`` with plain object maps
 (``rr:template`` / ``rml:reference`` / ``rr:constant``), referencing object
-maps (``rr:parentTriplesMap``), one ``rr:joinCondition`` (``rr:child`` /
-``rr:parent``), ``rr:datatype`` and ``rr:termType`` ``rr:IRI`` /
-``rr:Literal``.  Blank-node property lists, ``;``/``,`` lists, IRIs,
-prefixed names and string literals are handled by a small recursive-descent
-parser — enough to round-trip every mapping in the bundled testbeds.
+maps (``rr:parentTriplesMap``) with any number of ``rr:joinCondition``s
+(``rr:child`` / ``rr:parent``, all of which a joined pair meets),
+``rr:datatype`` and ``rr:termType`` ``rr:IRI`` / ``rr:Literal``.
+Blank-node property lists, ``;``/``,`` lists, IRIs, prefixed names and
+string literals are handled by a small recursive-descent parser — enough
+to round-trip every mapping in the bundled testbeds.
 
 What the engine does not implement is refused with a ``SyntaxError`` that
 names the term, never dropped: ``rr:language``, ``rr:termType
-rr:BlankNode``, ``rr:graphMap``, a second ``rr:joinCondition``, and a second
-value of a single-valued term (``rr:class``, ``rr:predicate``,
-``rr:objectMap``, ``rr:datatype``, ...).
+rr:BlankNode``, ``rr:graphMap``, and a second value of a single-valued
+term (``rr:class``, ``rr:predicate``, ``rr:objectMap``, ``rr:datatype``,
+...).
 """
 
 from __future__ import annotations
@@ -232,16 +233,17 @@ class _Parser:
             if parent:
                 pname = str(parent)
                 pname = (pname[1:-1] if pname.startswith("<") else pname).lstrip("#")
-                join = None
-                jc = self._get(om_props, "joinCondition")
-                if len(jc) > 1:
-                    raise SyntaxError(f"{where}: a second rr:joinCondition is not supported")
-                if jc:
-                    child = self.expand(self._one(jc[0], "child", where))
-                    par = self.expand(self._one(jc[0], "parent", where))
-                    join = JoinCondition(child=child, parent=par)
+                joins = []
+                for jc in self._get(om_props, "joinCondition"):
+                    child = self._one(jc, "child", where)
+                    par = self._one(jc, "parent", where)
+                    if child is None or par is None:
+                        raise SyntaxError(
+                            f"{where}: a rr:joinCondition needs rr:child and rr:parent"
+                        )
+                    joins.append(JoinCondition(self.expand(child), self.expand(par)))
                 obj: TermMap | RefObjectMap = RefObjectMap(
-                    parent_triples_map=pname, join=join
+                    parent_triples_map=pname, joins=tuple(joins)
                 )
             else:
                 obj = self.build_term_map(om_props, where)

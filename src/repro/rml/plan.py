@@ -178,17 +178,17 @@ def build_plan(doc: MappingDocument) -> MappingPlan:
         cols.update(op.subj_columns)
         use(op.source_key, op.subj_columns, op.subj_pattern)
         if op.kind == "OJM":
-            cols.add(op.join_child_column)
-            use(op.source_key, (op.join_child_column,))
+            cols.update(op.join_child_columns)
+            use(op.source_key, op.join_child_columns)
         else:
             cols.update(op.obj_columns)
             use(op.source_key, op.obj_columns, op.obj_pattern)
-    for psrc, pcol, ppat, pcols in exec_plan.pjtt_builds.values():
+    for psrc, pjcols, ppat, pcols in exec_plan.pjtt_builds.values():
         cols = refcols.setdefault(psrc, set())
         n_ops_per_src[psrc] = n_ops_per_src.get(psrc, 0) + 1
-        cols.add(pcol)
+        cols.update(pjcols)
         cols.update(pcols)
-        use(psrc, (pcol,))
+        use(psrc, pjcols)
         use(psrc, tuple(pcols), ppat)
 
     sources = {
@@ -336,8 +336,7 @@ def render_explain(
                 (
                     "join indexes",
                     [
-                        "PJTT "
-                        + pk.replace("\x1f", " on ")
+                        "PJTT {} on {}".format(*pk.split("\x1f", 1)).replace("\x1f", ", ")
                         for pk in g.pjtt_keys
                     ],
                 )
@@ -347,10 +346,10 @@ def render_explain(
             op = ops[i]
             extra = ""
             if op.kind == "OJM":
-                extra = (
-                    f" (join {op.join_child_column} = "
-                    f"{op.parent_join_column})"
-                )
+                extra = " (join {})".format(", ".join(
+                    f"{c} = {p}"
+                    for c, p in zip(op.join_child_columns, op.parent_join_columns)
+                ))
             rule_lines.append(
                 f"{op.kind:5s} {op.triples_map} -> "
                 f"{_shorten(op.predicate)}{extra}"

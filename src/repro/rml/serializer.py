@@ -42,10 +42,10 @@ def _triples_map(tm: TriplesMap) -> str:
         om = pom.object_map
         if isinstance(om, RefObjectMap):
             inner = f"rr:parentTriplesMap <#{om.parent_triples_map}>"
-            if om.join is not None:
+            for jc in om.joins:
                 inner += (
-                    f' ; rr:joinCondition [ rr:child "{om.join.child}" ;'
-                    f' rr:parent "{om.join.parent}" ]'
+                    f' ; rr:joinCondition [ rr:child "{jc.child}" ;'
+                    f' rr:parent "{jc.parent}" ]'
                 )
         else:
             inner = _term(om, "").strip()

@@ -253,11 +253,11 @@ def test_engines_agree_when_parents_repeat_pairs():
     tables = {"csv:child.csv": tb.child, "csv:parent.csv": tb.parent}
     plan = build_plan(tb.doc).exec_plan
     for op in (op for op in plan.ops if op.kind == "OJM"):
-        psrc, pcol, _ppat, pcols = plan.pjtt_builds[op.pjtt_key]
+        psrc, (pcol,), _ppat, pcols = plan.pjtt_builds[op.pjtt_key]
         keys = tables[psrc][pcol]
         d = _distinct_subjects(keys, zip(*(tables[psrc][c] for c in pcols)))
         raw = collections.Counter(keys)
-        ck = set(tables[op.source_key][op.join_child_column])
+        ck = set(tables[op.source_key][op.join_child_columns[0]])
         assert max(raw[k] for k in ck) > max(len(d[k]) for k in ck)
     runs = {
         "eager": dict(),

@@ -45,11 +45,13 @@ def _batches(n, batch_size, stream, block_rows):
 def _candidates(plan, op, tables):
     """Each child row's candidates: the distinct parent subjects of its
     join key."""
-    psrc, pcol, _ppat, pcols = plan.pjtt_builds[op.pjtt_key]
+    psrc, pjcols, _ppat, pcols = plan.pjtt_builds[op.pjtt_key]
     subjects = collections.defaultdict(set)
-    for key, *subj in zip(tables[psrc][pcol], *(tables[psrc][c] for c in pcols)):
+    pkeys = zip(*(tables[psrc][c] for c in pjcols))
+    for key, *subj in zip(pkeys, *(tables[psrc][c] for c in pcols)):
         subjects[key].add(tuple(subj))
-    return [len(subjects[k]) for k in tables[op.source_key][op.join_child_column]]
+    ckeys = zip(*(tables[op.source_key][c] for c in op.join_child_columns))
+    return [len(subjects[k]) for k in ckeys]
 
 
 def _op_batches(doc, tables, batch_size, stream, block_rows):

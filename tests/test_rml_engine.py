@@ -96,7 +96,7 @@ def test_pjtt_reuse_across_rules():
             predicate=f"http://repro.org/vocab/p{i}",
             object_map=RefObjectMap(
                 parent_triples_map="ExonMap1",
-                join=JoinCondition("ACCESSION_NUMBER", "ACCESSION_NUMBER"),
+                joins=(JoinCondition("ACCESSION_NUMBER", "ACCESSION_NUMBER"),),
             ),
         )
         for i in range(3)

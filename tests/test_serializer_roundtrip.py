@@ -58,7 +58,7 @@ def test_json_iterator_roundtrip():
                     predicate="http://ex.org/vocab/ownedBy",
                     object_map=RefObjectMap(
                         parent_triples_map="OwnerMap",
-                        join=JoinCondition(child="owner_id", parent="oid"),
+                        joins=(JoinCondition(child="owner_id", parent="oid"),),
                     ),
                 ),
             ),
@@ -71,8 +71,8 @@ def test_json_iterator_roundtrip():
     item = reparsed.triples_maps["ItemMap"]
     assert item.source.fmt == "json"
     assert item.source.iterator == "$.items[*]"
-    join = item.poms[1].object_map.join
-    assert join == JoinCondition(child="owner_id", parent="oid")
+    joins = item.poms[1].object_map.joins
+    assert joins == (JoinCondition(child="owner_id", parent="oid"),)
 
 
 def test_join_condition_roundtrip_multiple_parents():
@@ -92,7 +92,7 @@ def test_join_condition_roundtrip_multiple_parents():
                 predicate=f"http://ex.org/vocab/rel{i}",
                 object_map=RefObjectMap(
                     parent_triples_map=pname,
-                    join=JoinCondition(child=f"fk{i}", parent=f"K{i}"),
+                    joins=(JoinCondition(child=f"fk{i}", parent=f"K{i}"),),
                 ),
             )
         )

@@ -159,8 +159,12 @@ _MAP = PREFIXES + """<#T> a rr:TriplesMap ;
     ("; rr:graphMap [ rr:constant <http://g> ] ", "", 'rml:reference "v"', "", "graphMap"),
     ("", "; rr:graphMap [ rr:constant <http://g> ] ", 'rml:reference "v"', "", "graphMap"),
     ("", "", 'rml:reference "v"', "; rr:graphMap [ rr:constant <http://g> ] ", "graphMap"),
-    ("", "", 'rr:parentTriplesMap <#P> ; rr:joinCondition [ rr:child "a" ; rr:parent "k" ] ; '
-     'rr:joinCondition [ rr:child "b" ; rr:parent "k" ]', "", "joinCondition"),
+    # a second join condition is a composite key's; one without rr:parent is refused
+    pytest.param(
+        "", "", 'rr:parentTriplesMap <#P> ; rr:joinCondition [ rr:child "a" ; rr:parent "k" ] ; '
+        'rr:joinCondition [ rr:child "b" ]', "", "joinCondition",
+        id='--rr:parentTriplesMap <#P> ; rr:joinCondition [ rr:child "a" ; rr:parent "k" ] ; '
+           'rr:joinCondition [ rr:child "b" ; rr:parent "k" ]--joinCondition'),
     ("; rr:class <http://x/A> ; rr:class <http://x/B> ", "", 'rml:reference "v"', "", "class"),
     ("", "", 'rml:reference "v" ; rr:termType rr:IRI ; rr:datatype xsd:integer', "", "datatype"),
     ("; rr:termType rr:Literal ", "", 'rml:reference "v"', "", "IRIs"),
